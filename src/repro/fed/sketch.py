@@ -155,14 +155,18 @@ def encode_delta(m: jnp.ndarray, basis: jnp.ndarray, k: int) -> Sketch:
     coef = jnp.einsum("bdr,bdc->brc", basis, m32)
     resid = m32 - jnp.einsum("bdr,brc->bdc", basis, coef)
     resid_t = jnp.swapaxes(resid, 1, 2)  # (B, C, d1)
-    top_abs, idx = jax.lax.top_k(jnp.abs(resid_t), kk)
+    _, idx = jax.lax.top_k(jnp.abs(resid_t), kk)
     # Ship the RAW delta entries at those positions, not the residuals:
     # decode overwrites, so full coverage is exact (no a + (m - a) drift).
     vals = jnp.take_along_axis(jnp.swapaxes(m32, 1, 2), idx, axis=-1)
-    resid_sq = jnp.sum(resid_t * resid_t, axis=(1, 2))  # (B,)
-    kept_sq = jnp.sum(top_abs * top_abs, axis=(1, 2))
+    # Sum the dropped entries themselves rather than (total - kept): the
+    # difference of two differently ordered f32 sums leaves epsilon residue,
+    # whereas this is exactly zero at k == d1 and monotone in k.
+    dropped = jnp.put_along_axis(
+        resid_t * resid_t, idx, 0.0, axis=-1, inplace=False
+    )
     m_sq = jnp.sum(m32 * m32, axis=(1, 2))
-    energy_frac = jnp.maximum(resid_sq - kept_sq, 0.0) / jnp.maximum(m_sq, 1e-12)
+    energy_frac = jnp.sum(dropped, axis=(1, 2)) / jnp.maximum(m_sq, 1e-12)
     return Sketch(coef=coef, vals=vals, idx=idx, energy_frac=energy_frac)
 
 

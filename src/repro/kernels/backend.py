@@ -3,43 +3,34 @@
 One question, answered in one place: should a ``pallas_call`` run compiled
 (TPU) or in interpret mode (CPU/GPU hosts where Mosaic cannot lower)?
 
-Resolution order:
-  1. explicit ``interpret=`` argument at the call site (tests pin this),
-  2. ``REPRO_PALLAS_INTERPRET`` env var ("0"/"false" forces compiled,
-     anything else forces interpret) — the CLI ``--pallas-interpret``
-     flags set this,
-  3. platform autodetect: compiled on TPU, interpret elsewhere.
+The platform decides: compiled on TPU, interpreted elsewhere.  A call site
+may pass ``interpret=True`` explicitly (the CPU tests do), but never on a
+TPU backend — there the request is refused, so a kernel can never run
+interpreted on the chip it was written for.
 
 Kernel modules default their ``interpret`` parameter to ``None`` and call
 ``resolve_interpret`` so a bare ``lora_matmul(...)`` does the right thing on
-both the CPU CI container and real TPU hardware without any plumbing.
+both the CPU test host and real TPU hardware without any plumbing.
 """
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 import jax
 
-ENV_VAR = "REPRO_PALLAS_INTERPRET"
-
 
 def interpret_default() -> bool:
-    """True when Pallas kernels should run in interpret mode by default."""
-    env = os.environ.get(ENV_VAR)
-    if env is not None:
-        return env not in ("0", "false", "False")
+    """True when Pallas kernels run in interpret mode: every non-TPU backend."""
     return jax.default_backend() != "tpu"
 
 
 def resolve_interpret(interpret: Optional[bool]) -> bool:
-    """Apply the resolution order above to a call-site ``interpret`` arg."""
-    return interpret_default() if interpret is None else bool(interpret)
-
-
-def set_override(value: Optional[bool]) -> None:
-    """Process-wide override hook for CLI flags (None clears it)."""
-    if value is None:
-        os.environ.pop(ENV_VAR, None)
-    else:
-        os.environ[ENV_VAR] = "1" if value else "0"
+    """The platform default, or an explicit call-site choice off-TPU."""
+    if interpret is None:
+        return interpret_default()
+    if interpret and jax.default_backend() == "tpu":
+        raise ValueError(
+            "Pallas interpret mode requested on a TPU backend; kernels run "
+            "compiled on TPU (pass interpret=None)"
+        )
+    return bool(interpret)

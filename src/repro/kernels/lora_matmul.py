@@ -33,6 +33,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import backend
 
@@ -108,8 +109,8 @@ def lora_matmul(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), x.dtype),
         scratch_shapes=[
-            _vmem((bm, bn), jnp.float32),
-            _vmem((bm, rp), jnp.float32),
+            pltpu.VMEM((bm, bn), jnp.float32),
+            pltpu.VMEM((bm, rp), jnp.float32),
         ],
         interpret=interpret,
     )(xp, wp, ap, bp, s_arr)
@@ -227,8 +228,6 @@ def gathered_lora_matmul(
     directly from the pool — no ``(M, K, R)`` materialization ever exists.
     """
     interpret = backend.resolve_interpret(interpret)
-    from jax.experimental.pallas import tpu as pltpu
-
     m, kdim = x.shape
     _, n = w.shape
     n_slots, _, r = a_pool.shape
@@ -264,8 +263,8 @@ def gathered_lora_matmul(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k, s_ref: (i, j)),
         scratch_shapes=[
-            _vmem((bm, bn), jnp.float32),
-            _vmem((bm, rp), jnp.float32),
+            pltpu.VMEM((bm, bn), jnp.float32),
+            pltpu.VMEM((bm, rp), jnp.float32),
         ],
     )
     out_sorted = pl.pallas_call(
@@ -317,14 +316,3 @@ def gathered_lora_matmul_xla(
     lora = jnp.zeros((m, n), lo.dtype).at[order].set(jnp.take(lo, pos, axis=0))
     base = jnp.dot(x, w, preferred_element_type=jnp.float32)
     return (base + scale * lora).astype(x.dtype)
-
-
-def _vmem(shape, dtype):
-    from jax.experimental.pallas import tpu as pltpu
-
-    try:
-        return pltpu.VMEM(shape, dtype)
-    except Exception:  # interpret-mode fallback: generic scratch
-        import jax.experimental.pallas as pl_
-
-        return pl_.MemorySpace.ANY  # pragma: no cover

@@ -1,8 +1,7 @@
 """jit'd wrappers binding the Pallas kernels into the framework.
 
 Execution mode policy lives in ``repro.kernels.backend``: compiled on TPU,
-interpret elsewhere, with ``REPRO_PALLAS_INTERPRET`` / explicit ``interpret=``
-overrides (see that module's docstring for the resolution order).
+interpret elsewhere; an explicit ``interpret=True`` is honoured off-TPU only.
 """
 from __future__ import annotations
 
@@ -17,13 +16,9 @@ from repro.kernels import lora_matmul as _lm
 from repro.kernels import soft_threshold as _st
 from repro.kernels import ssd_scan as _ss
 
-# Back-compat alias (rpca_admm / svt_subspace historically imported this).
-_interpret_default = backend.interpret_default
-
-
 def soft_threshold(x: jnp.ndarray, t, *, interpret: Optional[bool] = None) -> jnp.ndarray:
     """Kernel-backed shrinkage; reshapes any rank to 2-D tiles."""
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = backend.resolve_interpret(interpret)
     shape = x.shape
     x2 = jnp.atleast_2d(x.reshape(-1, shape[-1]) if x.ndim >= 2 else x.reshape(1, -1))
     out = _st.soft_threshold(x2, t, interpret=interpret)
@@ -35,7 +30,7 @@ def lora_matmul(
     *, interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Fused y = xW + s(xA)B for inputs of any leading rank."""
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = backend.resolve_interpret(interpret)
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
     out = _lm.lora_matmul(x2, w, a, b, scale, interpret=interpret)
@@ -100,7 +95,7 @@ def local_attention(
     causal: bool = True, interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """(B, S, H, D) x (B, S, H, D) sliding-window attention (per-head fused)."""
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = backend.resolve_interpret(interpret)
     if q.ndim == 4:
         bsz, s, h, d = q.shape
         fold = lambda t: jnp.transpose(t, (0, 2, 1, 3)).reshape(bsz * h, s, d)
@@ -115,5 +110,5 @@ def ssd_scan(
     x: jnp.ndarray, da: jnp.ndarray, b: jnp.ndarray, c: jnp.ndarray, *,
     chunk: int = 256, interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = backend.resolve_interpret(interpret)
     return _ss.ssd_scan(x, da, b, c, chunk=chunk, interpret=interpret)

@@ -11,12 +11,15 @@ that the per-op path round-trips through HBM five times:
 
 This kernel fuses the whole tail: each (1, block_vec, n_clients) tile of
 M/L/Y is read once, S and the new Y are written once, and the blockwise
-residual partial sums accumulate into a per-module (B, 1) output across the
-inner grid dimension (TPU grids execute sequentially, so revisiting the same
-output block is the standard accumulation pattern).  Per-module scalars
-(rho, mu, threshold = rho * lam) ride along as (1, 1) blocks — the bucket
-mixes modules with different true vec dims, so every module carries its own
-ADMM constants.  See DESIGN.md §4 for the memory plan.
+residual partial sums accumulate into a per-module (B,) SMEM output across
+the inner grid dimension (TPU grids execute sequentially, so revisiting the
+same output is the standard accumulation pattern).  Per-module scalars
+(rho, mu, threshold = rho * lam) ride along as whole (B,) SMEM arrays read
+at ``program_id(0)`` — the bucket mixes modules with different true vec
+dims, so every module carries its own ADMM constants.  (A (1, 1) VMEM block
+over a (B, 1) array is refused by the TPU lowering: the last two block dims
+must be (8, 128)-divisible or span the array.)  See DESIGN.md §4 for the
+memory plan.
 
 The kernel is single-device by construction, which is exactly what the
 mesh-sharded loop (DESIGN.md §10) needs: each shard calls ``admm_tail`` on
@@ -34,15 +37,18 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import backend
 
 DEFAULT_BLOCK_VEC = 512
 
 
 def _kernel(rho_ref, mu_ref, th_ref, mask_ref, m_ref, l_ref, y_ref, s_ref, yo_ref, r_ref):
-    j = pl.program_id(1)
-    rho = rho_ref[0, 0]
-    mu = mu_ref[0, 0]
-    th = th_ref[0, 0]
+    i, j = pl.program_id(0), pl.program_id(1)
+    rho = rho_ref[i]
+    mu = mu_ref[i]
+    th = th_ref[i]
     msk = mask_ref[...]  # (1, 1, nc) client validity mask; all-ones when dense
     m = m_ref[...]
     l = l_ref[...]
@@ -56,11 +62,11 @@ def _kernel(rho_ref, mu_ref, th_ref, mask_ref, m_ref, l_ref, y_ref, s_ref, yo_re
 
     @pl.when(j == 0)
     def _init():
-        r_ref[0, 0] = part
+        r_ref[i] = part
 
     @pl.when(j > 0)
     def _acc():
-        r_ref[0, 0] += part
+        r_ref[i] += part
 
 
 @functools.partial(jax.jit, static_argnames=("block_vec", "interpret"))
@@ -97,10 +103,7 @@ def admm_tail(
       (S, Y_new, resid_sumsq) with resid_sumsq a (B,) float32 array of
       ``sum((M - L - S)^2)`` per module (active columns only when masked).
     """
-    if interpret is None:
-        from repro.kernels import backend
-
-        interpret = backend.interpret_default()
+    interpret = backend.resolve_interpret(interpret)
     if m.ndim != 3:
         raise ValueError(f"expected (B, vec, clients) input, got {m.shape}")
     if m.shape != l.shape or m.shape != y.shape:
@@ -112,10 +115,10 @@ def admm_tail(
         padder = lambda t: jnp.pad(t, ((0, 0), (0, pad_v), (0, 0)))
         m, l, y = padder(m), padder(l), padder(y)
     grid = (b, m.shape[1] // bv)
-    scal = lambda v: jnp.asarray(v, jnp.float32).reshape(b, 1)
+    scal = lambda v: jnp.asarray(v, jnp.float32).reshape(b)
     mvec = jnp.ones((nc,), jnp.float32) if mask is None else jnp.asarray(mask, jnp.float32)
     mvec = mvec.reshape(1, 1, nc)
-    sspec = pl.BlockSpec((1, 1), lambda i, j: (i, 0))
+    sspec = pl.BlockSpec(memory_space=pltpu.SMEM)  # whole (B,) array
     mspec = pl.BlockSpec((1, 1, nc), lambda i, j: (0, 0, 0))
     tspec = pl.BlockSpec((1, bv, nc), lambda i, j: (i, j, 0))
     s, y_new, rsq = pl.pallas_call(
@@ -126,10 +129,10 @@ def admm_tail(
         out_shape=[
             jax.ShapeDtypeStruct(m.shape, m.dtype),
             jax.ShapeDtypeStruct(m.shape, m.dtype),
-            jax.ShapeDtypeStruct((b, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b,), jnp.float32),
         ],
         interpret=interpret,
     )(scal(rho), scal(mu), scal(thresh), mvec, m, l, y)
     if pad_v:
         s, y_new = s[:, :d1, :], y_new[:, :d1, :]
-    return s, y_new, rsq[:, 0]
+    return s, y_new, rsq

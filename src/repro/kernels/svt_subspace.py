@@ -18,15 +18,16 @@ In subspace SVT mode (DESIGN.md §6) one ADMM iteration factors into
 
 This kernel fuses all of (b): each (1, block_vec, d2) tile of M/S/Y is read
 once, L/S'/Y' tiles are written once, and *two* accumulators ride across the
-inner grid dimension — the per-module residual partial sums ``(B, 1)`` and
+inner grid dimension — the per-module residual partial sums ``(B,)`` and
 the next iteration's Gram matrix ``(B, d2, d2)`` (TPU grids execute the
 inner dimension sequentially, so revisiting the same output block is the
 standard accumulation pattern).  Folding the Gram accumulation in removes
 the separate full pass over X' that the unfused path pays, so the only
 per-iteration work outside this kernel is the O(d2^2 r) basis algebra.
 
-Per-module scalars (rho, mu, thresh) ride as (1, 1) blocks; the optional
-client validity mask ride as one VMEM-resident (1, 1, d2) block exactly as
+Per-module scalars (rho, mu, thresh) and the residual sums live in SMEM as
+whole (B,) arrays indexed by ``program_id(0)``; the optional client
+validity mask rides as one VMEM-resident (1, 1, d2) block exactly as
 in ``kernels/rpca_admm`` — S'/Y'/resid are masked in-register so padded
 cohort slots stay exactly zero, and M's masked columns are zero on entry so
 the Gram accumulator never sees them.  L is deliberately *not* masked here
@@ -53,6 +54,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import backend
 
 DEFAULT_BLOCK_VEC = 512
 
@@ -61,10 +65,10 @@ def _kernel(
     rho_ref, mu_ref, th_ref, mask_ref, p_ref, m_ref, s_ref, y_ref,
     l_ref, so_ref, yo_ref, r_ref, g_ref,
 ):
-    j = pl.program_id(1)
-    rho = rho_ref[0, 0]
-    mu = mu_ref[0, 0]
-    th = th_ref[0, 0]
+    i, j = pl.program_id(0), pl.program_id(1)
+    rho = rho_ref[i]
+    mu = mu_ref[i]
+    th = th_ref[i]
     msk = mask_ref[0]  # (1, d2) client validity; all-ones when dense
     p = p_ref[0]  # (d2, d2) shrink projector
     m = m_ref[0]  # (block_vec, d2)
@@ -85,12 +89,12 @@ def _kernel(
 
     @pl.when(j == 0)
     def _init():
-        r_ref[0, 0] = r_part
+        r_ref[i] = r_part
         g_ref[0] = g_part
 
     @pl.when(j > 0)
     def _acc():
-        r_ref[0, 0] += r_part
+        r_ref[i] += r_part
         g_ref[0] += g_part
 
 
@@ -127,10 +131,7 @@ def subspace_apply(
       and G' the (B, d2, d2) float32 Gram of the *next* iterate
       ``M - S' + rho Y'`` (what ``SubspaceState.g`` carries forward).
     """
-    if interpret is None:
-        from repro.kernels import backend
-
-        interpret = backend.interpret_default()
+    interpret = backend.resolve_interpret(interpret)
     if m.ndim != 3:
         raise ValueError(f"expected (B, vec, clients) input, got {m.shape}")
     if m.shape != s.shape or m.shape != y.shape:
@@ -144,10 +145,10 @@ def subspace_apply(
         padder = lambda t: jnp.pad(t, ((0, 0), (0, pad_v), (0, 0)))
         m, s, y = padder(m), padder(s), padder(y)
     grid = (b, m.shape[1] // bv)
-    scal = lambda v: jnp.asarray(v, jnp.float32).reshape(b, 1)
+    scal = lambda v: jnp.asarray(v, jnp.float32).reshape(b)
     mvec = jnp.ones((d2,), jnp.float32) if mask is None else jnp.asarray(mask, jnp.float32)
     mvec = mvec.reshape(1, 1, d2)
-    sspec = pl.BlockSpec((1, 1), lambda i, j: (i, 0))
+    sspec = pl.BlockSpec(memory_space=pltpu.SMEM)  # whole (B,) array
     mspec = pl.BlockSpec((1, 1, d2), lambda i, j: (0, 0, 0))
     pspec = pl.BlockSpec((1, d2, d2), lambda i, j: (i, 0, 0))
     tspec = pl.BlockSpec((1, bv, d2), lambda i, j: (i, j, 0))
@@ -160,24 +161,24 @@ def subspace_apply(
             jax.ShapeDtypeStruct(m.shape, m.dtype),
             jax.ShapeDtypeStruct(m.shape, m.dtype),
             jax.ShapeDtypeStruct(m.shape, m.dtype),
-            jax.ShapeDtypeStruct((b, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b,), jnp.float32),
             jax.ShapeDtypeStruct((b, d2, d2), jnp.float32),
         ],
         interpret=interpret,
     )(scal(rho), scal(mu), scal(thresh), mvec, p.astype(jnp.float32), m, s, y)
     if pad_v:
         l, s_new, y_new = l[:, :d1, :], s_new[:, :d1, :], y_new[:, :d1, :]
-    return l, s_new, y_new, rsq[:, 0], g_next
+    return l, s_new, y_new, rsq, g_next
 
 
 def _kernel_factored(
     rho_ref, mu_ref, th_ref, mask_ref, vr_ref, m_ref, y_ref, f_ref,
     l_ref, so_ref, yo_ref, r_ref,
 ):
-    j = pl.program_id(1)
-    rho = rho_ref[0, 0]
-    mu = mu_ref[0, 0]
-    th = th_ref[0, 0]
+    i, j = pl.program_id(0), pl.program_id(1)
+    rho = rho_ref[i]
+    mu = mu_ref[i]
+    th = th_ref[i]
     msk = mask_ref[0]  # (1, d2) client validity; all-ones when dense
     vr = vr_ref[0]  # (d2, r) this shard's Ritz basis rows
     m = m_ref[0]  # (block_vec, d2)
@@ -195,11 +196,11 @@ def _kernel_factored(
 
     @pl.when(j == 0)
     def _init():
-        r_ref[0, 0] = part
+        r_ref[i] = part
 
     @pl.when(j > 0)
     def _acc():
-        r_ref[0, 0] += part
+        r_ref[i] += part
 
 
 @functools.partial(jax.jit, static_argnames=("block_vec", "interpret"))
@@ -241,10 +242,7 @@ def subspace_apply_factored(
       *this shard's partial* ``sum((M - L - S')^2)`` — the caller psums it
       across shards before the convergence check.
     """
-    if interpret is None:
-        from repro.kernels import backend
-
-        interpret = backend.interpret_default()
+    interpret = backend.resolve_interpret(interpret)
     if m.ndim != 3:
         raise ValueError(f"expected (B, vec, clients) input, got {m.shape}")
     if m.shape != y.shape:
@@ -261,10 +259,10 @@ def subspace_apply_factored(
         padder = lambda t: jnp.pad(t, ((0, 0), (0, pad_v), (0, 0)))
         m, y, f = padder(m), padder(y), padder(f)
     grid = (b, m.shape[1] // bv)
-    scal = lambda v: jnp.asarray(v, jnp.float32).reshape(b, 1)
+    scal = lambda v: jnp.asarray(v, jnp.float32).reshape(b)
     mvec = jnp.ones((d2,), jnp.float32) if mask is None else jnp.asarray(mask, jnp.float32)
     mvec = mvec.reshape(1, 1, d2)
-    sspec = pl.BlockSpec((1, 1), lambda i, j: (i, 0))
+    sspec = pl.BlockSpec(memory_space=pltpu.SMEM)  # whole (B,) array
     mspec = pl.BlockSpec((1, 1, d2), lambda i, j: (0, 0, 0))
     vspec = pl.BlockSpec((1, d2, r), lambda i, j: (i, 0, 0))
     tspec = pl.BlockSpec((1, bv, d2), lambda i, j: (i, j, 0))
@@ -278,11 +276,11 @@ def subspace_apply_factored(
             jax.ShapeDtypeStruct(m.shape, m.dtype),
             jax.ShapeDtypeStruct(m.shape, m.dtype),
             jax.ShapeDtypeStruct(m.shape, m.dtype),
-            jax.ShapeDtypeStruct((b, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b,), jnp.float32),
         ],
         interpret=interpret,
     )(scal(rho), scal(mu), scal(thresh), mvec, vr.astype(jnp.float32),
       m, y, f.astype(m.dtype))
     if pad_v:
         l, s_new, y_new = l[:, :d1, :], s_new[:, :d1, :], y_new[:, :d1, :]
-    return l, s_new, y_new, rsq[:, 0]
+    return l, s_new, y_new, rsq

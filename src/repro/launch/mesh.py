@@ -9,9 +9,16 @@ from __future__ import annotations
 from typing import Tuple
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.config import MeshConfig
+
+
+def _auto_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
+    """``jax.make_mesh`` with Auto axes: the sharding code places arrays with
+    ``with_sharding_constraint`` and ``shard_map``, which Explicit axes (the
+    ``make_mesh`` default) refuse."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -19,12 +26,12 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     Multi-pod: (2, 16, 16) = 512 chips (pod, data, model)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_debug_mesh(shape: Tuple[int, ...] = (1, 1), axes=("data", "model")) -> Mesh:
     """1-device mesh for CPU smoke runs of the mesh code path."""
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def client_axes(mesh: Mesh) -> Tuple[str, ...]:
@@ -67,7 +74,7 @@ def make_host_mesh(n: int) -> Mesh:
             f"{n} in the environment BEFORE the first jax init (jax locks "
             "the device count at first use; see launch/dryrun.py)."
         )
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return _auto_mesh((n, 1), ("data", "model"))
 
 
 def named(mesh: Mesh, spec_tree):

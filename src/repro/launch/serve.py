@@ -26,7 +26,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import configs as cfglib
-from repro.kernels import backend as kbackend
 from repro.models import (
     decode_step,
     extend_caches,
@@ -36,6 +35,7 @@ from repro.models import (
 )
 from repro.serve import AdapterPool, adapter_view
 from repro.utils import get_logger
+from repro.utils.compile_cache import enable_compile_cache
 
 log = get_logger("serve")
 
@@ -164,15 +164,9 @@ def main(argv=None):
     ap.add_argument("--merged", action="store_true",
                     help="legacy path: serve the MEAN of all adapters "
                          "(every tenant gets the same averaged adapter)")
-    ap.add_argument(
-        "--pallas-interpret", choices=["auto", "0", "1"], default="auto",
-        help="force Pallas interpret mode on/off (auto = by backend)",
-    )
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
-
-    if args.pallas_interpret != "auto":
-        kbackend.set_override(args.pallas_interpret == "1")
+    enable_compile_cache()
 
     cfg = cfglib.get_config(args.arch)
     if args.reduced:

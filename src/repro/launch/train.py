@@ -1,8 +1,8 @@
 """Federated LoRA fine-tuning driver.
 
-Executes the same federated step the dry-run lowers — on this CPU container
-with reduced configs (``--reduced``), on a TPU slice with the production
-mesh (``--mesh single|multi``).  Per round: every client takes
+Executes the same federated step the dry-run lowers — on the CPU with
+reduced configs (``--reduced``), on a TPU at published widths
+(``chip_smoke.py`` drives it there).  Per round: every client takes
 ``--local-steps`` LoRA steps on its own Markov-LM shard, deltas are
 aggregated with ``--aggregator`` (FedRPCA by default), checkpoints are
 written every ``--ckpt-every`` rounds.
@@ -20,6 +20,16 @@ delta corruption: ``nan:0.1``, ``dropout:0.2,straggler:0.5``, ...); the
 pre-aggregation quarantine (``fed.guard``) switches on with them (force
 with ``--guard`` / ``--no-guard``), and the run exits nonzero if the
 final state is non-finite or a corrupted column ever escaped the screen.
+Without ``--faults`` a round that the land-time supervisor retried or
+degraded to FedAvg is a failure too: nothing was injected, so a
+non-finite aggregation is a bug, and the run exits 1.
+
+``main`` returns a summary dict: ``initial_eval_loss``,
+``final_eval_loss``, ``rounds`` (one dict of scalar diagnostics and phase
+timers per round) and ``last_deltas`` (the last local phase's stacked
+client deltas, ``--client-ranks`` masks applied, before any injected
+fault), so in-process callers such as ``chip_smoke.py`` can check the run
+without parsing logs.
 
 Example (CPU):
   PYTHONPATH=src python -m repro.launch.train --arch mamba2-130m --reduced \
@@ -76,6 +86,7 @@ from repro.fed.pipeline import run_rounds
 from repro.launch import steps as steps_lib
 from repro.models import init_lora_params, init_params, loss_fn
 from repro.utils import get_logger
+from repro.utils.compile_cache import enable_compile_cache
 
 log = get_logger("train")
 
@@ -258,6 +269,7 @@ def main(argv=None):
             fault_model = faults_lib.FaultModel(fcfg)
             log.info("fault injection on: %s", fcfg)
     guard_on = fault_model is not None if args.guard is None else args.guard
+    enable_compile_cache()
     guard_cfg = guard_lib.GuardConfig() if guard_on else None
 
     cfg = cfglib.get_config(args.arch)
@@ -365,6 +377,8 @@ def main(argv=None):
     # builds its round's batch from a per-round generator — seeded by
     # (seed, round) rather than a shared stream, so a resumed run consumes
     # exactly the batches an uninterrupted run would have seen.
+    last_deltas = [None]  # the newest local phase's deltas, for the summary
+
     def cli_local(state: _CliState, n_active=None):
         del n_active
         r = state.round_idx
@@ -380,6 +394,7 @@ def main(argv=None):
             deltas = jax.tree_util.tree_map(
                 lambda d, mk: d * mk.astype(d.dtype), deltas, rank_masks
             )
+        last_deltas[0] = deltas
         fault_slots = None
         if fault_model is not None:
             if mask is None:
@@ -478,6 +493,7 @@ def main(argv=None):
 
     fault_totals = {"injected": 0.0, "caught": 0.0, "escapes": 0.0,
                     "degraded": 0.0, "retries": 0.0}
+    round_log = []
 
     def on_round(r, state: _CliState, diags):
         rg = start_round + r  # global round index (resume offset)
@@ -488,6 +504,7 @@ def main(argv=None):
         fault_totals["degraded"] += float(diags.get("degraded", 0.0))
         fault_totals["retries"] += float(diags.get("supervisor_retry", 0.0))
         timers = {k: diags.get(k, 0.0) for k in ("t_local_s", "t_agg_s", "t_overlap_s")}
+        round_log.append({"round": rg, **{k: float(v) for k, v in diags.items()}})
         extra = "".join(
             f"  {k}={float(v):.3g}" for k, v in diags.items()
             if k != "mean_local_loss" and not k.startswith("t_")
@@ -511,7 +528,8 @@ def main(argv=None):
                     metadata={"arch": cfg.name, "round": rg + 1},
                 )
 
-    log.info("initial eval loss %.4f", evaluate(base, lora, cfg, test.tokens))
+    initial_loss = evaluate(base, lora, cfg, test.tokens)
+    log.info("initial eval loss %.4f", initial_loss)
     if depth:
         log.info("pipeline on: staleness bound %d", depth)
     state = run_rounds(
@@ -531,6 +549,13 @@ def main(argv=None):
         if fault_totals["escapes"]:
             log.error("quarantine escape: a screened round was not finite")
             sys.exit(1)
+    if fault_model is None and (fault_totals["degraded"] or fault_totals["retries"]):
+        log.error(
+            "no fault was injected, yet %d round(s) were retried and %d "
+            "degraded to FedAvg: the aggregation produced non-finite output",
+            int(fault_totals["retries"]), int(fault_totals["degraded"]),
+        )
+        sys.exit(1)
     final_finite = all(
         bool(jnp.all(jnp.isfinite(leaf)))
         for leaf in jax.tree_util.tree_leaves(lora)
@@ -538,7 +563,14 @@ def main(argv=None):
     if not final_finite:
         log.error("final global LoRA state is non-finite")
         sys.exit(1)
-    log.info("final eval loss %.4f", evaluate(base, lora, cfg, test.tokens))
+    final_loss = evaluate(base, lora, cfg, test.tokens)
+    log.info("final eval loss %.4f", final_loss)
+    return {
+        "initial_eval_loss": initial_loss,
+        "final_eval_loss": final_loss,
+        "rounds": round_log,
+        "last_deltas": last_deltas[0],
+    }
 
 
 if __name__ == "__main__":

@@ -121,9 +121,8 @@ class TestCodec:
         s = sketch_lib.encode_delta(m, basis, 24)
         m_hat = sketch_lib.decode_into_bucket(s, basis)
         assert np.array_equal(np.asarray(m_hat), np.asarray(m))
-        # energy_frac is computed analytically (resid_sq - kept_sq), so
-        # float summation order leaves epsilon residue even at full k.
-        assert float(jnp.max(s.energy_frac)) < 1e-5
+        # energy_frac sums the dropped entries, and at full k none are.
+        assert float(jnp.max(s.energy_frac)) == 0.0
 
     def test_partial_k_energy_monotone(self, rng):
         m = jnp.asarray(rng.normal(size=(2, 32, 5)), jnp.float32)
