@@ -536,7 +536,7 @@ def rpca_diag_summary(diag) -> dict:
         # only under sketch-uplink plans, DESIGN.md §12) so per-round
         # bytes land in the training logs next to the carry health.
         for k in (
-            "fallback_count", "carry_hit_rate", "bytes_up",
+            "fallback_count", "svt_steps", "carry_hit_rate", "bytes_up",
             "bytes_down_basis", "uplink_hit_rate", "uplink_dense_falls",
         ):
             if k in diag.scalars:

@@ -106,6 +106,7 @@ class Bucket:
     weights: jnp.ndarray | None = None  # (cohort_size,) float32, normalized
 
 
+@jax.named_scope("agg.pack")
 def pack(
     stacked: PyTree,
     *,
@@ -291,6 +292,7 @@ def pack(
     return buckets, spec
 
 
+@jax.named_scope("agg.unpack")
 def unpack(spec: PackSpec, updates: Mapping[BucketKey, jnp.ndarray]) -> PyTree:
     """Invert ``pack``: per-bucket (total_modules, padded_vec) update arrays
     back to a pytree shaped like one client's delta."""
@@ -525,42 +527,43 @@ def _fedrpca_bucket(
     new_carry = None
     if carry is not None:
         res, new_carry = res
-    w_post = w_uniform if col_scaled else bucket.weights
-    diag_extra = {}
-    if cfg.guard_energy_k > 0:
-        # Sparse-energy quarantine (DESIGN.md §11): per-module per-client
-        # column scores replace the shared weight vector with a guarded
-        # (flagged clients exactly zero) per-module one.  Off (k=0) keeps
-        # the legacy shared-vector einsums bit-for-bit.
-        client_energy = rpca_lib.client_sparse_energy(m, res.sparse)
-        gw, flags = rpca_lib.energy_guard_weights(
-            client_energy, cfg.guard_energy_k, base_w=w_post,
-            valid=bucket.client_mask,
-        )
-        low_mean = jnp.einsum("mvc,mc->mv", res.low_rank, gw)
-        sparse_mean = jnp.einsum("mvc,mc->mv", res.sparse, gw)
-        diag_extra = {
-            "client_energy": jnp.max(client_energy, axis=0),
-            "client_flagged": jnp.max(flags, axis=0),
+    with jax.named_scope("agg.tail"):
+        w_post = w_uniform if col_scaled else bucket.weights
+        diag_extra = {}
+        if cfg.guard_energy_k > 0:
+            # Sparse-energy quarantine (DESIGN.md §11): per-module per-client
+            # column scores replace the shared weight vector with a guarded
+            # (flagged clients exactly zero) per-module one.  Off (k=0) keeps
+            # the legacy shared-vector einsums bit-for-bit.
+            client_energy = rpca_lib.client_sparse_energy(m, res.sparse)
+            gw, flags = rpca_lib.energy_guard_weights(
+                client_energy, cfg.guard_energy_k, base_w=w_post,
+                valid=bucket.client_mask,
+            )
+            low_mean = jnp.einsum("mvc,mc->mv", res.low_rank, gw)
+            sparse_mean = jnp.einsum("mvc,mc->mv", res.sparse, gw)
+            diag_extra = {
+                "client_energy": jnp.max(client_energy, axis=0),
+                "client_flagged": jnp.max(flags, axis=0),
+            }
+        elif w_post is None:
+            low_mean = jnp.mean(res.low_rank, axis=-1)
+            sparse_mean = jnp.mean(res.sparse, axis=-1)
+        else:
+            low_mean = jnp.einsum("mvc,c->mv", res.low_rank, w_post)
+            sparse_mean = jnp.einsum("mvc,c->mv", res.sparse, w_post)
+        # E^(t) = ||S . 1|| / ||M . 1|| per module (App. B.3); padded rows and
+        # masked columns are 0 so they drop out of both sums.
+        energy = jax.vmap(sparse_energy_ratio)(m, res.sparse)
+        if cfg.adaptive_beta:
+            beta = jnp.clip(1.0 / jnp.maximum(energy, 1e-12), cfg.beta_min, cfg.beta_max)
+        else:
+            beta = jnp.full(energy.shape, cfg.beta, jnp.float32)
+        update = low_mean + beta[:, None] * sparse_mean
+        diag = {
+            "beta": beta, "energy": energy, "residual": res.residual,
+            "n_iter": res.n_iter, **diag_extra, **uplink_diag,
         }
-    elif w_post is None:
-        low_mean = jnp.mean(res.low_rank, axis=-1)
-        sparse_mean = jnp.mean(res.sparse, axis=-1)
-    else:
-        low_mean = jnp.einsum("mvc,c->mv", res.low_rank, w_post)
-        sparse_mean = jnp.einsum("mvc,c->mv", res.sparse, w_post)
-    # E^(t) = ||S . 1|| / ||M . 1|| per module (App. B.3); padded rows and
-    # masked columns are 0 so they drop out of both sums.
-    energy = jax.vmap(sparse_energy_ratio)(m, res.sparse)
-    if cfg.adaptive_beta:
-        beta = jnp.clip(1.0 / jnp.maximum(energy, 1e-12), cfg.beta_min, cfg.beta_max)
-    else:
-        beta = jnp.full(energy.shape, cfg.beta, jnp.float32)
-    update = low_mean + beta[:, None] * sparse_mean
-    diag = {
-        "beta": beta, "energy": energy, "residual": res.residual,
-        **diag_extra, **uplink_diag,
-    }
     return update, diag, new_carry
 
 
@@ -868,6 +871,7 @@ def init_agg_carry(plan: AggPlan) -> AggCarry:
     return out
 
 
+@jax.named_scope("agg.pack")
 def _sub_bucket(bucket: Bucket, idx: tuple) -> Bucket:
     """Static module-row subset of a bucket (a tier's view)."""
     ia = jnp.asarray(idx, jnp.int32)
@@ -898,10 +902,13 @@ def aggregate_planned(
     bucket *tier* as one batched call with its own rank cap and its own
     slot of the carry, and returns ``(update, new_carry)`` — plus an
     ``EngineDiagnostics`` when ``with_diagnostics`` (fedrpca adds
-    per-module ``live_rank`` and the ``fallback_count`` /
-    ``carry_hit_rate`` scalars when a carry threads; sketch-uplink plans
-    add the ``bytes_up`` / ``bytes_down_basis`` / ``uplink_hit_rate`` /
-    ``uplink_dense_falls`` wire-accounting scalars, DESIGN.md §12).
+    per-module ``live_rank`` and the ``fallback_count`` / ``svt_steps`` /
+    ``carry_hit_rate`` scalars when a carry threads: ``svt_steps`` counts
+    the SVT steps run, summed over the carried tiers, so ``fallback_count
+    / svt_steps`` is the share that fell back to the exact eigh;
+    sketch-uplink plans add the ``bytes_up`` / ``bytes_down_basis`` /
+    ``uplink_hit_rate`` / ``uplink_dense_falls`` wire-accounting scalars,
+    DESIGN.md §12).
 
     ``carry=None`` (or ``{}``) with a carrying plan cold-starts every
     bucket; ``carry_mode="none"`` plans pass the empty carry through
@@ -951,7 +958,7 @@ def aggregate_planned(
         + client_keys
     }
     new_carry: AggCarry = {}
-    falls, hits = [], []
+    falls, hits, svt_steps = [], [], []
     # Uplink byte accounting (sketch plans only): per-tier wire bytes and
     # gate hits, summed into round scalars (DESIGN.md §12).
     up_bytes, down_bytes, up_hits = [], [], []
@@ -985,6 +992,7 @@ def aggregate_planned(
                 per_mod["live_rank"] = c2.n_live.astype(jnp.float32)
                 falls.append(c2.fall_count)
                 hits.append(c2.hit)
+                svt_steps.append(jnp.max(d["n_iter"]))
         else:
             upd = jnp.zeros((b_total, padded_vec), jnp.float32)
             per_mod = {
@@ -996,22 +1004,25 @@ def aggregate_planned(
                 ck = (bkey, name)
                 sub = _sub_bucket(bucket, idx)
                 u_t, d_t, c2 = run_tier(sub, ck, cap)
-                ia = jnp.asarray(idx, jnp.int32)
-                upd = upd.at[ia].set(u_t.astype(jnp.float32))
-                for k in ("beta", "energy", "residual"):
-                    per_mod[k] = per_mod[k].at[ia].set(d_t[k])
-                for k in client_keys:
-                    per_mod[k] = (
-                        d_t[k] if k not in per_mod
-                        else jnp.maximum(per_mod[k], d_t[k])
-                    )
+                with jax.named_scope("agg.unpack"):
+                    ia = jnp.asarray(idx, jnp.int32)
+                    upd = upd.at[ia].set(u_t.astype(jnp.float32))
+                    for k in ("beta", "energy", "residual"):
+                        per_mod[k] = per_mod[k].at[ia].set(d_t[k])
+                    for k in client_keys:
+                        per_mod[k] = (
+                            d_t[k] if k not in per_mod
+                            else jnp.maximum(per_mod[k], d_t[k])
+                        )
+                    if plan.carry:
+                        new_carry[ck] = c2
+                        per_mod["live_rank"] = per_mod["live_rank"].at[ia].set(
+                            c2.n_live.astype(jnp.float32)
+                        )
                 if plan.carry:
-                    new_carry[ck] = c2
-                    per_mod["live_rank"] = per_mod["live_rank"].at[ia].set(
-                        c2.n_live.astype(jnp.float32)
-                    )
                     falls.append(c2.fall_count)
                     hits.append(c2.hit)
+                    svt_steps.append(jnp.max(d_t["n_iter"]))
             updates[bkey] = upd
         for k in arrays:
             arrays[k][bkey] = per_mod[k]
@@ -1023,6 +1034,7 @@ def aggregate_planned(
     if plan.carry:
         scalars = {
             "fallback_count": sum(falls, jnp.zeros((), jnp.int32)),
+            "svt_steps": sum(svt_steps, jnp.zeros((), jnp.int32)),
             "carry_hit_rate": jnp.mean(jnp.stack(hits)),
         }
     if up_bytes:

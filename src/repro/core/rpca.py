@@ -313,6 +313,7 @@ def _ritz_projector(g, t, v, n_sweeps, shrink_fn):
     return p, vr, n_live, rel
 
 
+@jax.named_scope("agg.svt")
 def svt_subspace_step(
     t: jnp.ndarray,
     state: SubspaceState,
@@ -487,6 +488,7 @@ def init_bucket_carry(
 
 
 @_f32_matmuls
+@jax.named_scope("agg.admm")
 def robust_pca(
     m: jnp.ndarray,
     *,
@@ -580,6 +582,7 @@ def robust_pca(
 
 
 @_f32_matmuls
+@jax.named_scope("agg.admm")
 def robust_pca_fixed_iters(
     m: jnp.ndarray,
     *,
@@ -671,6 +674,7 @@ def batched_robust_pca(ms: jnp.ndarray, **kwargs) -> RPCAResult:
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("agg.svt")
 def svt_gram_batched(
     x: jnp.ndarray, t: jnp.ndarray, shrink_fn: Callable = soft_threshold
 ) -> jnp.ndarray:
@@ -688,6 +692,7 @@ def svt_gram_batched(
 
 
 @_f32_matmuls
+@jax.named_scope("agg.admm")
 def robust_pca_bucket(
     m: jnp.ndarray,
     true_dims: jnp.ndarray | None = None,
@@ -1089,6 +1094,7 @@ def mesh_client_shards(mesh) -> int:
 
 
 @_f32_matmuls
+@jax.named_scope("agg.admm")
 def robust_pca_bucket_sharded(
     m: jnp.ndarray,
     true_dims: jnp.ndarray | None = None,
@@ -1232,7 +1238,10 @@ def robust_pca_bucket_sharded(
         return idx
 
     def inner(m_k, dims_f, cmask_k, *rest):
-        gs = lambda x: jax.lax.psum(x, ax)
+        def gs(x):
+            with jax.named_scope("agg.psum"):
+                return jax.lax.psum(x, ax)
+
         m_k = m_k * cmask_k
         n_eff = jnp.maximum(gs(jnp.sum(cmask_k)), 1.0)
         abs_sum = gs(jnp.sum(jnp.abs(m_k), axis=(1, 2)))
@@ -1337,11 +1346,13 @@ def robust_pca_bucket_sharded(
                 rsq = jnp.concatenate([gs(o[3]) for o in outs], axis=0)
                 return l, s, y_new, jnp.sqrt(rsq)
 
+        @jax.named_scope("agg.svt")
         def exact_svt(x_k, t):
             # Exact fallback: the full d2 x d2 Gram needs every column, so
             # gather X once, eigh replicated, and slice the projector
             # application back to this shard's client columns/basis rows.
-            xg = jax.lax.all_gather(x_k, ax, axis=2, tiled=True)
+            with jax.named_scope("agg.psum"):
+                xg = jax.lax.all_gather(x_k, ax, axis=2, tiled=True)
             g = jnp.einsum("bdc,bde->bce", xg, xg)
             w_eig, v_full = jnp.linalg.eigh(g)  # ascending
             s_ = jnp.sqrt(jnp.maximum(w_eig, 0.0))
@@ -1373,6 +1384,7 @@ def robust_pca_bucket_sharded(
                 zs.append(jnp.einsum("bdc,bdr->bcr", x_k[lo:hi], wc))
             return jnp.concatenate(ws, axis=0), jnp.concatenate(zs, axis=0)
 
+        @jax.named_scope("agg.svt")
         def ritz_factors(x_k, t, v_k, n_sweeps):
             # Power sweeps on local rows: W = X V is the only non-tiny
             # collective; (G V)_k = X_k^T W never leaves the shard.
@@ -1413,6 +1425,7 @@ def robust_pca_bucket_sharded(
             l_k = jnp.einsum("bds,bs,bcs->bdc", xvr, coef, vr_k)
             return l_k, vr_k, n_live, rel
 
+        @jax.named_scope("agg.svt")
         def svt_step(x_k, v_k, n_live, rel_prev, cold):
             t = rho
 

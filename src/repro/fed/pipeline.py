@@ -53,6 +53,7 @@ from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Any, Callable, NamedTuple, Optional
 
 import jax
+from jax.profiler import TraceAnnotation
 
 PyTree = Any
 
@@ -266,9 +267,16 @@ def run_rounds(
         pipeline shows ~0 here);
       * ``t_overlap_s`` — aggregation in-flight time hidden behind
         subsequent local work (dispatch-to-ready latency minus the blocked
-        wait; 0 by construction when synchronous);
-      * ``t_round_s`` — ``t_local_s + t_agg_s``, the round's host-visible
-        cost.
+        wait; 0 by construction when synchronous).
+
+    The same intervals are host spans on the profiler's clock
+    (``jax.profiler.TraceAnnotation``; next to free when no profiler is
+    active): ``fed.round`` per loop iteration; ``fed.local`` is the
+    interval ``t_local_s`` times; ``fed.land`` the one ``t_agg_s`` times,
+    with children ``fed.land.wait`` (the in-flight result) and
+    ``fed.land.apply`` (apply and its block); ``fed.agg.dispatch`` the
+    aggregation dispatch, ``fed.agg.worker`` its run on the worker thread,
+    and ``fed.on_round`` the callback.
     """
     if staleness < 0:
         raise ValueError(f"staleness must be >= 0, got {staleness}")
@@ -289,37 +297,40 @@ def run_rounds(
     chain: list = [None]
 
     def land(entry: _InFlight, state):
-        t0 = time.perf_counter()
-        out = entry.out.result() if isinstance(entry.out, Future) else entry.out
-        upd, new_carry, diags = out
-        finite = diags.get("update_finite")
-        if finite is not None and float(finite) == 0.0:
-            # Supervisor ladder (DESIGN.md §11): a non-finite update never
-            # reaches the global.  A poisoned carry is the usual culprit —
-            # retry bitwise-cold first, then give up on RPCA entirely.
-            extra = {}
-            if cold_carry is not None:
-                warnings.warn(
-                    f"round {entry.round_idx}: non-finite aggregation "
-                    "output; retrying with a cold carry"
-                )
-                upd, new_carry, diags = phases.agg(
-                    cold_carry(), entry.bundle, entry.scale
-                )
-                extra["supervisor_retry"] = 1.0
-                finite = diags.get("update_finite")
-            if finite is not None and float(finite) == 0.0 and fallback is not None:
-                warnings.warn(
-                    f"round {entry.round_idx}: aggregation still non-finite "
-                    "after the cold-carry retry; degrading to masked FedAvg"
-                )
-                upd, new_carry, diags = fallback(entry.bundle, entry.scale)
-            diags = {**diags, **extra}
-            chain[0] = None
-        new_lora = apply_fn(state.lora_global, upd)
-        if timers:
-            jax.block_until_ready(new_lora)
-        now = time.perf_counter()
+        with TraceAnnotation("fed.land"):
+            t0 = time.perf_counter()
+            with TraceAnnotation("fed.land.wait"):
+                out = entry.out.result() if isinstance(entry.out, Future) else entry.out
+            upd, new_carry, diags = out
+            finite = diags.get("update_finite")
+            if finite is not None and float(finite) == 0.0:
+                # Supervisor ladder (DESIGN.md §11): a non-finite update never
+                # reaches the global.  A poisoned carry is the usual culprit —
+                # retry bitwise-cold first, then give up on RPCA entirely.
+                extra = {}
+                if cold_carry is not None:
+                    warnings.warn(
+                        f"round {entry.round_idx}: non-finite aggregation "
+                        "output; retrying with a cold carry"
+                    )
+                    upd, new_carry, diags = phases.agg(
+                        cold_carry(), entry.bundle, entry.scale
+                    )
+                    extra["supervisor_retry"] = 1.0
+                    finite = diags.get("update_finite")
+                if finite is not None and float(finite) == 0.0 and fallback is not None:
+                    warnings.warn(
+                        f"round {entry.round_idx}: aggregation still non-finite "
+                        "after the cold-carry retry; degrading to masked FedAvg"
+                    )
+                    upd, new_carry, diags = fallback(entry.bundle, entry.scale)
+                diags = {**diags, **extra}
+                chain[0] = None
+            with TraceAnnotation("fed.land.apply"):
+                new_lora = apply_fn(state.lora_global, upd)
+                if timers:
+                    jax.block_until_ready(new_lora)
+            now = time.perf_counter()
         t_agg = now - t0
         adaptive.observe(diags)
         state = state._replace(lora_global=new_lora, agg_carry=new_carry)
@@ -329,8 +340,8 @@ def run_rounds(
                 diags["t_local_s"] = entry.t_local
                 diags["t_agg_s"] = t_agg
                 diags["t_overlap_s"] = max(0.0, (now - entry.t_dispatch) - t_agg)
-                diags["t_round_s"] = entry.t_local + t_agg
-            on_round(entry.round_idx, state, diags)
+            with TraceAnnotation("fed.on_round"):
+                on_round(entry.round_idx, state, diags)
         return state
 
     def dispatch(state, bundle, round_scale):
@@ -343,10 +354,11 @@ def run_rounds(
             # Single FIFO worker: prev was submitted earlier, so it has
             # already run and result() never blocks — this is how one
             # carry chain threads through K out-of-state dispatches.
-            carry = prev.result()[1] if prev is not None else carry0
-            out = phases.agg(carry, bundle, round_scale)
-            jax.block_until_ready(out[0])  # materialize on the worker
-            return out
+            with TraceAnnotation("fed.agg.worker"):
+                carry = prev.result()[1] if prev is not None else carry0
+                out = phases.agg(carry, bundle, round_scale)
+                jax.block_until_ready(out[0])  # materialize on the worker
+                return out
 
         fut = worker.submit(work)
         chain[0] = fut
@@ -355,33 +367,37 @@ def run_rounds(
     state = phases.prep_state(state)
     try:
         for r in range(rounds):
-            # This round's actual staleness: how many updates its local
-            # phase's global is missing right now.  Round 0 has tau=0 even
-            # in a pipelined run, so its update lands undamped.
-            tau = len(queue)
-            round_scale = adaptive.scale_for(tau) if scale is None else scale
-            t0 = time.perf_counter()
-            # The local phase reads the CURRENT buffer: with aggregations in
-            # flight, its lora_global is up to `staleness` updates behind.
-            state, bundle = phases.local(state, n_active)
-            if timers:
-                jax.block_until_ready(bundle.loss_mean)
-            t_local = time.perf_counter() - t0
-            # Land the oldest in-flight aggregation BEFORE dispatching this
-            # round's: the dispatch budget frees up and the landed carry is
-            # current in case the chain was severed by the supervisor.
-            oldest = queue.pop_ready()
-            if oldest is not None:
-                state = land(oldest, state)
-            out = dispatch(state, bundle, round_scale)
-            landed = queue.push(
-                _InFlight(
-                    r, bundle.loss_mean, out, bundle, round_scale,
-                    t_local, time.perf_counter(),
+            with TraceAnnotation("fed.round"):
+                # This round's actual staleness: how many updates its local
+                # phase's global is missing right now.  Round 0 has tau=0
+                # even in a pipelined run, so its update lands undamped.
+                tau = len(queue)
+                round_scale = adaptive.scale_for(tau) if scale is None else scale
+                with TraceAnnotation("fed.local"):
+                    t0 = time.perf_counter()
+                    # The local phase reads the CURRENT buffer: with
+                    # aggregations in flight, its lora_global is up to
+                    # `staleness` updates behind.
+                    state, bundle = phases.local(state, n_active)
+                    if timers:
+                        jax.block_until_ready(bundle.loss_mean)
+                    t_local = time.perf_counter() - t0
+                # Land the oldest in-flight aggregation BEFORE dispatching
+                # this round's: the dispatch budget frees up and the landed
+                # carry is current in case the supervisor severed the chain.
+                oldest = queue.pop_ready()
+                if oldest is not None:
+                    state = land(oldest, state)
+                with TraceAnnotation("fed.agg.dispatch"):
+                    out = dispatch(state, bundle, round_scale)
+                landed = queue.push(
+                    _InFlight(
+                        r, bundle.loss_mean, out, bundle, round_scale,
+                        t_local, time.perf_counter(),
+                    )
                 )
-            )
-            if landed is not None:
-                state = land(landed, state)
+                if landed is not None:
+                    state = land(landed, state)
         for entry in queue.drain():
             state = land(entry, state)
     finally:

@@ -782,9 +782,9 @@ def run_simulation(
     ``cfg.pipeline=False`` runs the staleness-0 (synchronous) schedule;
     ``cfg.pipeline=True`` overlaps each round's local phase with the
     previous round's in-flight aggregation, bounded by ``cfg.staleness``.
-    Per-round phase timers (``t_local_s`` / ``t_agg_s`` / ``t_overlap_s`` /
-    ``t_round_s``) ride to ``log_fn`` beside the accuracy either way, so
-    the pipeline win is visible straight from the logs.
+    Per-round phase timers (``t_local_s`` / ``t_agg_s`` / ``t_overlap_s``)
+    ride to ``log_fn`` beside the accuracy either way, so the pipeline win
+    is visible straight from the logs.
     """
     from repro.fed import pipeline as pipeline_lib
 

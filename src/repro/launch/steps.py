@@ -97,28 +97,32 @@ def make_local_step(
             from repro.optim.optimizers import apply_updates
 
             opt = adam(local_lr)
-            state = opt.init(lora_global)
+            with jax.named_scope("local.opt"):
+                state = opt.init(lora_global)
 
             def one(carry, _):
                 lora, state = carry
-                loss, g = local_loss_grad(lora, client_batch)
-                upd, state = opt.update(g, state, lora)
-                return (apply_updates(lora, upd), state), loss
+                with jax.named_scope("local.grad"):
+                    loss, g = local_loss_grad(lora, client_batch)
+                with jax.named_scope("local.opt"):
+                    upd, state = opt.update(g, state, lora)
+                    return (apply_updates(lora, upd), state), loss
 
             (lora, _), losses = jax.lax.scan(
                 one, (lora_global, state), None, length=local_steps
             )
+        else:
+            # Plain SGD local steps.
+            def one(lora, _):
+                with jax.named_scope("local.grad"):
+                    loss, g = local_loss_grad(lora, client_batch)
+                with jax.named_scope("local.opt"):
+                    return tree_add(lora, tree_scale(g, -local_lr)), loss
+
+            lora, losses = jax.lax.scan(one, lora_global, None, length=local_steps)
+        with jax.named_scope("local.delta"):
             delta = jax.tree_util.tree_map(lambda a, b: a - b, lora, lora_global)
             return delta, losses[-1]
-
-        # Plain SGD local steps.
-        def one(lora, _):
-            loss, g = local_loss_grad(lora, client_batch)
-            return tree_add(lora, tree_scale(g, -local_lr)), loss
-
-        lora, losses = jax.lax.scan(one, lora_global, None, length=local_steps)
-        delta = jax.tree_util.tree_map(lambda a, b: a - b, lora, lora_global)
-        return delta, losses[-1]
 
     def local_step(base, lora_global, batch, agg_key=None):
         extras = {k: batch[k] for k in _EXTRA_KEYS if k in batch}
@@ -167,15 +171,17 @@ def make_local_step(
             deltas, losses = jax.vmap(gated_fn)(
                 mask, batch["tokens"], batch["labels"], *extras.values()
             )
-        if mask is None:
-            loss = jnp.mean(losses)
-        else:
-            loss = jnp.sum(mask * losses) / jnp.maximum(jnp.sum(mask), 1.0)
+        with jax.named_scope("local.delta"):
+            if mask is None:
+                loss = jnp.mean(losses)
+            else:
+                loss = jnp.sum(mask * losses) / jnp.maximum(jnp.sum(mask), 1.0)
         return deltas, loss, mask
 
     return local_step
 
 
+@jax.named_scope("agg.apply")
 def apply_update(lora_global: PyTree, scaled_update: PyTree) -> PyTree:
     """Land-time composition: fold an already-scaled update into the global.
 
@@ -275,13 +281,15 @@ def make_agg_step(
                 plan, deltas, agg_carry, key=agg_key, mask=mask,
                 weights=weights, with_diagnostics=True,
             )
-            scaled = jax.tree_util.tree_map(lambda u: scale * u, update)
+            with jax.named_scope("agg.tail"):
+                scaled = jax.tree_util.tree_map(lambda u: scale * u, update)
             return scaled, rpca_diag_summary(ediag), new_carry
         update = aggregate(
             deltas, agg_cfg, engine=engine, key=agg_key, mask=mask, weights=weights,
             mesh=mesh,
         )
-        scaled = jax.tree_util.tree_map(lambda u: scale * u, update)
+        with jax.named_scope("agg.tail"):
+            scaled = jax.tree_util.tree_map(lambda u: scale * u, update)
         return scaled, {}
 
     agg_step.carry_on = carry_on

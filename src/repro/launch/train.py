@@ -24,6 +24,15 @@ Without ``--faults`` a round that the land-time supervisor retried or
 degraded to FedAvg is a failure too: nothing was injected, so a
 non-finite aggregation is a bug, and the run exits 1.
 
+``--trace-dir DIR --trace-rounds A:B`` writes a JAX profiler trace of
+rounds A to B-1 to DIR, from the landing of round A-1 (or the start) to
+the first local phase after round B-1 has landed: the round driver's ``fed.*`` host spans
+(``fed/pipeline.py``) and device operations tagged with the ``local.*`` /
+``agg.*`` scopes of the steps (``launch/steps.py``, ``core/``).  Under
+``--pipeline`` round A's local phase has run by then.  Each round's log line names
+the jitted functions compiled while it ran (``compiled=...``), so a
+landing that recompiles shows in the log.
+
 ``main`` returns a summary dict: ``initial_eval_loss``,
 ``final_eval_loss``, ``rounds`` (one dict of scalar diagnostics and phase
 timers per round) and ``last_deltas`` (the last local phase's stacked
@@ -218,6 +227,13 @@ def main(argv=None):
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=10)
     ap.add_argument("--resume", action="store_true")
+    ap.add_argument("--trace-dir", default=None,
+                    help="write a JAX profiler trace of the --trace-rounds "
+                         "rounds here: the fed.* host spans and the local.* "
+                         "/ agg.* scopes of the device operations")
+    ap.add_argument("--trace-rounds", default=None, metavar="A:B",
+                    help="rounds to trace with --trace-dir: A up to but not "
+                         "including B (default: every round)")
     args = ap.parse_args(argv)
 
     carry_on = (
@@ -236,6 +252,16 @@ def main(argv=None):
         )
     if args.staleness < 0:
         ap.error(f"--staleness must be >= 0, got {args.staleness}")
+    trace_from, trace_to = 0, args.rounds
+    if args.trace_rounds is not None:
+        if args.trace_dir is None:
+            ap.error("--trace-rounds needs --trace-dir")
+        try:
+            trace_from, trace_to = (int(x) for x in args.trace_rounds.split(":"))
+        except ValueError:
+            ap.error(f"--trace-rounds takes A:B, got {args.trace_rounds!r}")
+        if not 0 <= trace_from < trace_to:
+            ap.error(f"--trace-rounds A:B needs 0 <= A < B, got {args.trace_rounds!r}")
     uplink_cfg = sketch_lib.parse_uplink(args.uplink)
     if uplink_cfg.active and not carry_on:
         # The sketch basis IS the carried RPCA subspace; without a carry
@@ -378,10 +404,21 @@ def main(argv=None):
     # (seed, round) rather than a shared stream, so a resumed run consumes
     # exactly the batches an uninterrupted run would have seen.
     last_deltas = [None]  # the newest local phase's deltas, for the summary
+    # The profiler: None before, "on", "ending" once round B-1 has landed
+    # (it stops at the next local phase, when that round's spans are
+    # closed), "done".
+    tracing = [None]
+
+    def start_trace():
+        jax.profiler.start_trace(args.trace_dir)
+        tracing[0] = "on"
 
     def cli_local(state: _CliState, n_active=None):
         del n_active
         r = state.round_idx
+        if tracing[0] == "ending":
+            jax.profiler.stop_trace()
+            tracing[0] = "done"
         batch = build_batches(
             client_tokens, args.per_client_batch, args.seq,
             np.random.default_rng((args.seed, 1000 + r)),
@@ -494,6 +531,11 @@ def main(argv=None):
     fault_totals = {"injected": 0.0, "caught": 0.0, "escapes": 0.0,
                     "degraded": 0.0, "retries": 0.0}
     round_log = []
+    compiled = []  # jitted functions compiled since the last landing
+
+    def on_compile(event, secs, fun_name="?", **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiled.append(fun_name)
 
     def on_round(r, state: _CliState, diags):
         rg = start_round + r  # global round index (resume offset)
@@ -509,11 +551,18 @@ def main(argv=None):
             f"  {k}={float(v):.3g}" for k, v in diags.items()
             if k != "mean_local_loss" and not k.startswith("t_")
         )
+        if compiled:
+            extra += f"  compiled={','.join(compiled)}"
+            compiled.clear()
         log.info(
             "round %03d  local_loss=%.4f%s  t_local=%.2fs t_agg=%.2fs "
             "t_overlap=%.2fs", rg, float(diags["mean_local_loss"]), extra,
             timers["t_local_s"], timers["t_agg_s"], timers["t_overlap_s"],
         )
+        if tracing[0] == "on" and rg >= trace_to - 1:
+            tracing[0] = "ending"
+        elif args.trace_dir and tracing[0] is None and rg == trace_from - 1:
+            start_trace()
         if args.ckpt_dir and (rg + 1) % args.ckpt_every == 0:
             if carry_on:
                 save_checkpoint(
@@ -532,10 +581,18 @@ def main(argv=None):
     log.info("initial eval loss %.4f", initial_loss)
     if depth:
         log.info("pipeline on: staleness bound %d", depth)
-    state = run_rounds(
-        phases, _CliState(lora, carry, start_round),
-        max(args.rounds - start_round, 0), staleness=depth, on_round=on_round,
-    )
+    if args.trace_dir and trace_from <= start_round < trace_to:
+        start_trace()
+    jax.monitoring.register_event_duration_secs_listener(on_compile)
+    try:
+        state = run_rounds(
+            phases, _CliState(lora, carry, start_round),
+            max(args.rounds - start_round, 0), staleness=depth, on_round=on_round,
+        )
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_compile)
+        if tracing[0] in ("on", "ending"):
+            jax.profiler.stop_trace()
     lora = state.lora_global
     if fault_model is not None or guard_cfg is not None:
         inj, caught = fault_totals["injected"], fault_totals["caught"]

@@ -108,6 +108,36 @@ class TestTrainMain:
             assert bool(jnp.any(mk == 0))
             np.testing.assert_array_equal(np.asarray(d * mk), np.asarray(d))
 
+    def test_traces_the_rounds_asked_for_and_logs_compiles(self, no_cache, tmp_path):
+        """``--trace-rounds 1:2`` traces round 1 alone (round 0 lands
+        before the trace starts), and each round's log line names what
+        compiled while it ran: everything in round 0, nothing after."""
+        import logging
+
+        from jax.profiler import ProfileData
+
+        lines = []
+        handler = logging.Handler()
+        handler.emit = lambda rec: lines.append(rec.getMessage())
+        logger = logging.getLogger("repro.train")
+        logger.addHandler(handler)
+        try:
+            train.main(TINY_TRAIN + ["--rounds", "3", "--trace-dir", str(tmp_path),
+                                     "--trace-rounds", "1:2"])
+        finally:
+            logger.removeHandler(handler)
+        rounds = [ln for ln in lines if ln.startswith("round ")]
+        assert len(rounds) == 3
+        assert "compiled=" in rounds[0] and "local_step" in rounds[0]
+        assert "agg_step" in rounds[0]
+        assert not any("compiled=" in ln for ln in rounds[1:])
+        (path,) = tmp_path.glob("**/*.xplane.pb")
+        names = [e.name for p in ProfileData.from_file(str(path)).planes
+                 for line in p.lines for e in line.events if e.name.startswith("fed.")]
+        for span in ("fed.round", "fed.local", "fed.agg.dispatch", "fed.land",
+                     "fed.land.wait", "fed.land.apply", "fed.on_round"):
+            assert names.count(span) == 1, span
+
     def test_degraded_round_without_faults_exits_1(self, monkeypatch, no_cache):
         """A non-finite FedRPCA update is retried cold, then degraded to
         FedAvg; the final state is finite, yet with no fault injected the

@@ -469,3 +469,24 @@ class TestShardedFusedTail:
         assert all(f == 0 for f in falls[1:])
         for a, b in zip(base_outs, outs):
             assert_trees_close(a, b, atol=5e-4, rtol=5e-4)
+
+
+@needs4
+@pytest.mark.parametrize("svt_mode", ["gram", "subspace"])
+def test_sharded_collectives_run_under_agg_psum(svt_mode):
+    """Every collective of the sharded ADMM loop carries the ``agg.psum``
+    scope inside ``agg.admm`` in its HLO ``op_name``: what a device trace's
+    collective time is attributed by."""
+    import re
+
+    from repro.launch import steps as steps_lib
+
+    tree = round_trees(np.random.default_rng(0), rounds=1)[0]
+    cfg = AggregatorConfig(method="fedrpca", rpca_iters=3, svt_mode=svt_mode)
+    step = steps_lib.make_agg_step(cfg, mesh=make_host_mesh(4))
+    text = jax.jit(step).lower(tree).compile().as_text()
+    collectives = [ln for ln in text.splitlines()
+                   if re.search(r"= \S+ (all-reduce|all-gather)(-start)?\(", ln)]
+    assert collectives
+    for ln in collectives:
+        assert re.search(r'op_name="[^"]*agg\.admm/[^"]*agg\.psum', ln), ln

@@ -165,9 +165,51 @@ class TestPipelinedRounds:
         assert [r for r, _ in logs] == list(range(5))
         assert len(hist) == 5
         for _, d in logs:
-            assert {"t_local_s", "t_agg_s", "t_overlap_s", "t_round_s"} <= set(d)
+            assert {"t_local_s", "t_agg_s", "t_overlap_s"} <= set(d)
             assert d["t_local_s"] >= 0 and d["t_agg_s"] >= 0
             assert d["t_overlap_s"] >= 0
+
+    def test_spans_nest_and_match_timers(self, task, tmp_path):
+        """Under a profiler, every round leaves its ``fed.*`` host spans,
+        nested as the driver runs them, and ``fed.local`` / ``fed.land``
+        cover what ``t_local_s`` / ``t_agg_s`` time."""
+        from jax.profiler import ProfileData
+
+        rounds = 3
+        cfg = cfg_for(task, rounds=rounds, pipeline=True, staleness=1)
+        logs = []
+        with jax.profiler.trace(str(tmp_path)):
+            run(task, cfg, log_fn=lambda r, d: logs.append(d))
+        (path,) = tmp_path.glob("**/*.xplane.pb")
+        spans = {}
+        for plane in ProfileData.from_file(str(path)).planes:
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("fed."):
+                        spans.setdefault(e.name, []).append((e.start_ns, e.end_ns))
+        spans = {k: sorted(v) for k, v in spans.items()}
+        assert {k: len(v) for k, v in spans.items()} == {
+            k: rounds for k in ("fed.round", "fed.local", "fed.agg.dispatch",
+                                "fed.agg.worker", "fed.land", "fed.land.wait",
+                                "fed.land.apply", "fed.on_round")}
+
+        def inside(child, parents):
+            return any(ps <= child[0] and child[1] <= pe for ps, pe in parents)
+
+        for name in ("fed.local", "fed.agg.dispatch"):
+            assert all(inside(c, spans["fed.round"]) for c in spans[name])
+        for name in ("fed.land.wait", "fed.land.apply"):
+            assert all(inside(c, spans["fed.land"]) for c in spans[name])
+        # Rounds 0 and 1 land inside the next round's iteration; the last in
+        # the drain, after the loop.
+        assert [inside(c, spans["fed.round"]) for c in spans["fed.land"]] == [
+            True, True, False]
+        # The callback runs after its landing, not within it.
+        assert not any(inside(c, spans["fed.land"]) for c in spans["fed.on_round"])
+        for (s, e), d in zip(spans["fed.local"], logs):
+            assert abs((e - s) * 1e-9 - d["t_local_s"]) < 2e-3
+        for (s, e), d in zip(spans["fed.land"], logs):
+            assert abs((e - s) * 1e-9 - d["t_agg_s"]) < 2e-3
 
     def test_staleness_one_converges(self, task):
         """Delayed, damped updates must not wreck convergence (the
