@@ -24,11 +24,12 @@ inputs in federated LoRA are ``(r*d) x n_clients`` with ``n_clients`` tiny
 (<= 100), so ``G = X^T X`` is a small symmetric matrix; ``eigh(G)`` yields the
 right singular vectors and squared singular values, and
 
-    SVT_t(X) = X @ (V * (shrink(s, t) / s)) @ V^T
+    SVT_t(X) = X @ P,   P = V diag(shrink(s, t) / s) V^T
 
 never materializes the tall U factor.  This is numerically identical to the
 SVD route for full-column-rank X (guarded by an eps on s) and is MXU-friendly:
-two small matmuls + one tiny eigh instead of a LAPACK-style SVD.
+two matmuls over the long side (the Gram and X @ P), one tiny C x C product
+and one tiny eigh instead of a LAPACK-style SVD.
 """
 from __future__ import annotations
 
@@ -71,23 +72,35 @@ def soft_threshold(x: jnp.ndarray, t) -> jnp.ndarray:
     return jnp.sign(x) * jnp.maximum(jnp.abs(x) - t, 0.0)
 
 
+def _shrink_projector(gram, t, shrink_fn):
+    """Exact SVT projector of ``X`` from its Gram ``G = X^T X``:
+    ``P = V diag(shrink(s, t) / s) V^T`` over the full eigenbasis, so that
+    ``SVT_t(X) = X @ P``.  ``gram`` is (..., n, n) and ``t`` broadcasts
+    against the (..., n) singular values.  Returns ``(P, V, shrink(s, t))``
+    with ``V`` in eigh's ascending order."""
+    w, v = jnp.linalg.eigh(gram)
+    s = jnp.sqrt(jnp.maximum(w, 0.0))
+    s_shrunk = shrink_fn(s, t)
+    coef = jnp.where(s > _EPS, s_shrunk / jnp.maximum(s, _EPS), 0.0)
+    p = (v * coef[..., None, :]) @ jnp.swapaxes(v, -1, -2)
+    return p, v, s_shrunk
+
+
 def svt_gram(x: jnp.ndarray, t, shrink_fn: Callable = soft_threshold) -> jnp.ndarray:
     """Singular-value thresholding via the Gram matrix (thin side).
 
     Works on any 2-D ``x``; the eigendecomposition is taken on the smaller
-    Gram matrix so cost is O(min(d1,d2)^3 + d1*d2*min(d1,d2)).
+    Gram matrix and the shrink is applied as one projector, ``X @ P``, so
+    the long side is streamed twice (the Gram and the projection) and cost
+    is O(min(d1,d2)^3 + d1*d2*min(d1,d2)).
     """
     d1, d2 = x.shape
     transpose = d1 < d2
     if transpose:
         x = x.T  # now tall: rows >= cols
-    # G = X^T X  (cols x cols), symmetric PSD.
-    gram = x.T @ x
-    w, v = jnp.linalg.eigh(gram)  # ascending eigenvalues
-    s = jnp.sqrt(jnp.maximum(w, 0.0))
-    s_shrunk = shrink_fn(s, t)
-    coef = jnp.where(s > _EPS, s_shrunk / jnp.maximum(s, _EPS), 0.0)
-    low_rank = (x @ (v * coef[None, :])) @ v.T
+    gram = jnp.einsum("dc,de->ce", x, x)  # X^T X (cols x cols), symmetric PSD
+    p, _, _ = _shrink_projector(gram, t, shrink_fn)
+    low_rank = x @ p
     return low_rank.T if transpose else low_rank
 
 
@@ -237,11 +250,7 @@ def subspace_init(m: jnp.ndarray, rank: int, true_cols: int | None = None) -> Su
 def _exact_projector(g, t, r, shrink_fn):
     """Full-eigh fallback: exact SVT projector P with all d2 directions,
     plus the top-r eigenbasis to (re)seed the warm-start carry."""
-    w, v_full = jnp.linalg.eigh(g)  # ascending
-    s = jnp.sqrt(jnp.maximum(w, 0.0))
-    s_shrunk = shrink_fn(s, t[:, None])
-    coef = jnp.where(s > _EPS, s_shrunk / jnp.maximum(s, _EPS), 0.0)
-    p = jnp.einsum("bnk,bk,bmk->bnm", v_full, coef, v_full)
+    p, v_full, s_shrunk = _shrink_projector(g, t[:, None], shrink_fn)
     # Top-r eigenbasis in eigh's ascending order (top directions LAST) —
     # the same column convention the Ritz path stores, so consumers that
     # truncate a carried basis (engine.migrate_carry) can slice trailing
@@ -680,10 +689,12 @@ def svt_gram_batched(
 ) -> jnp.ndarray:
     """Batched Gram-trick SVT: ``x`` is (B, d1, d2), ``t`` per-module (B,).
 
-    A vmap of ``svt_gram`` — one batched eigh + two batched matmuls; the
-    static transpose decision is shared by the whole bucket.  Padded zero
-    rows contribute nothing to the Gram matrix and stay exactly zero in the
-    thresholded output (DESIGN.md §3), so bucket padding is lossless.
+    A vmap of ``svt_gram`` — one batched eigh, two batched matmuls over the
+    vec dimension (the Gram and ``X @ P``) and one C x C product forming
+    ``P``; the static transpose decision is shared by the whole bucket.
+    Padded zero rows contribute nothing to the Gram matrix and stay exactly
+    zero in the thresholded output (DESIGN.md §3), so bucket padding is
+    lossless.
     ``shrink_fn`` must broadcast over an array threshold (the jnp reference
     does; the scalar-threshold Pallas shrink kernel does not — the fused-tail
     kernel covers the S update instead).
@@ -899,7 +910,11 @@ def robust_pca_bucket(
     else:
 
         def step(l, s, y):
-            l = svt_gram_batched(m - s + rho[:, None, None] * y, rho, shrink_fn)
+            # The barrier keeps XLA from fusing the tail's M - L into X @ P
+            # as a second output, which would hold X, L and M - L live at
+            # once: one state-sized buffer more than the step needs.
+            l = jax.lax.optimization_barrier(
+                svt_gram_batched(m - s + rho[:, None, None] * y, rho, shrink_fn))
             s, y, rnorm = tail(l, y)
             return l, s, y, rnorm / m_norm
 

@@ -1,5 +1,6 @@
 """Robust-PCA: recovery, SVT equivalence, and algebraic properties."""
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -8,9 +9,11 @@ from hypothesis import given, settings, strategies as st
 from repro.core import (
     batched_robust_pca,
     robust_pca,
+    robust_pca_bucket,
     robust_pca_fixed_iters,
     soft_threshold,
     svt_gram,
+    svt_gram_batched,
     svt_svd,
 )
 
@@ -24,13 +27,66 @@ def planted(n, m, rank, sparsity, scale=5.0, seed=0):
     return low, sp
 
 
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs in its parameters."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if isinstance(sub, jax.extend.core.Jaxpr):
+                    yield from _eqns(sub)
+
+
+def _dots(jaxpr):
+    return [e for e in _eqns(jaxpr) if e.primitive.name == "dot_general"]
+
+
+def _long_dots(jaxpr, vec):
+    """The dot_generals with an operand that carries the ``vec`` dimension."""
+    return [e for e in _dots(jaxpr) if any(vec in v.aval.shape for v in e.invars)]
+
+
 class TestSVT:
-    @pytest.mark.parametrize("shape", [(64, 8), (8, 64), (128, 128), (33, 7)])
-    def test_gram_matches_svd(self, shape, rng):
+    @pytest.mark.parametrize("shape,pad", [
+        pytest.param((64, 8), 0, id="shape0"),
+        pytest.param((8, 64), 0, id="shape1"),
+        pytest.param((128, 128), 0, id="shape2"),
+        pytest.param((33, 7), 0, id="shape3"),
+        pytest.param((33, 7), 31, id="padded_rows"),
+    ])
+    def test_gram_matches_svd(self, shape, pad, rng):
         x = jnp.asarray(rng.normal(size=shape), jnp.float32)
+        x = jnp.concatenate([x, jnp.zeros((pad, shape[1]), jnp.float32)])
         for t in (0.0, 0.5, 3.0, 100.0):
             a, b = svt_gram(x, t), svt_svd(x, t)
             np.testing.assert_allclose(a, b, atol=2e-4, rtol=1e-3)
+            assert not np.any(np.asarray(a[shape[0]:]))
+
+    @pytest.mark.parametrize("dims,vec,cols", [([64, 40, 17], 64, 6), ([5, 3, 1], 5, 8)])
+    def test_batched_padded_bucket(self, dims, vec, cols, rng):
+        """Zero-padded rows stay exactly zero and the true rows match the
+        unpadded call, in the tall bucket and in the wide (transposed) one."""
+        x = np.zeros((len(dims), vec, cols), np.float32)
+        for i, d in enumerate(dims):
+            x[i, :d] = rng.normal(size=(d, cols))
+        t = jnp.asarray([0.3, 1.0, 2.0], jnp.float32)
+        out = np.asarray(svt_gram_batched(jnp.asarray(x), t))
+        for i, d in enumerate(dims):
+            assert not np.any(out[i, d:])
+            np.testing.assert_allclose(
+                out[i, :d], svt_gram(jnp.asarray(x[i, :d]), t[i]), atol=1e-5, rtol=1e-5)
+
+    @pytest.mark.parametrize("shape", [(64, 6), (6, 64)])
+    def test_gram_streams_x_twice(self, shape):
+        """The shrink is one projector: the long side feeds the Gram and
+        X @ P and nothing else, and P is one C x C product."""
+        jaxpr = jax.make_jaxpr(lambda x: svt_gram(x, 0.5))(jnp.ones(shape)).jaxpr
+        gram, proj = _long_dots(jaxpr, 64)
+        assert gram.invars[0] is gram.invars[1] is proj.invars[0]
+        assert gram.outvars[0].aval.shape == (6, 6)
+        assert proj.outvars[0].aval.shape == (64, 6)
+        assert len(_dots(jaxpr)) == 3
 
     def test_svt_zero_threshold_identity(self, rng):
         x = jnp.asarray(rng.normal(size=(50, 10)), jnp.float32)
@@ -39,6 +95,28 @@ class TestSVT:
     def test_svt_large_threshold_zero(self, rng):
         x = jnp.asarray(rng.normal(size=(50, 10)), jnp.float32)
         np.testing.assert_allclose(svt_gram(x, 1e6), jnp.zeros_like(x), atol=1e-5)
+
+
+class TestBucketLoopStructure:
+    @pytest.mark.parametrize("tol", [None, 1e-6])
+    def test_gram_loop_streams_state_twice(self, tol):
+        """Each gram-mode ADMM iteration runs two matmuls over the vec
+        dimension, both reading X = M - S + rho Y: the Gram and X @ P.  L
+        reaches the tail through a barrier, so that XLA cannot fuse the
+        tail into X @ P and keep X, L and M - L live at once."""
+        m = jnp.ones((3, 64, 6), jnp.float32)
+        jaxpr = jax.make_jaxpr(
+            lambda m: robust_pca_bucket(m, n_iter=4, tol=tol, svt_mode="gram").low_rank
+        )(m).jaxpr
+        (loop,) = [e for e in _eqns(jaxpr) if e.primitive.name in ("scan", "while")]
+        body = loop.params["jaxpr" if tol is None else "body_jaxpr"].jaxpr
+        gram, proj = _long_dots(body, 64)
+        assert gram.invars[0] is gram.invars[1] is proj.invars[0]
+        assert gram.outvars[0].aval.shape == (3, 6, 6)
+        assert proj.outvars[0].aval.shape == (3, 64, 6)
+        assert len(_dots(body)) == 3
+        (user,) = [e for e in body.eqns if proj.outvars[0] in e.invars]
+        assert user.primitive.name == "optimization_barrier"
 
 
 class TestRPCA:

@@ -73,11 +73,12 @@ class TestSVTSubspaceSingle:
         cold = svt_subspace(x, 0.1, rank=2)
         warm = svt_subspace(x, 0.1, cold.v, rank=2)
         assert bool(warm.fell_back)
-        # The exact path forms X (V c V^T) where svt_gram forms (X V c) V^T:
-        # the same product associated differently, whose f32 error scales
-        # with |x|.  x is 10x the unit data of the cold-start test, so its
-        # atol of 2e-5 becomes 2e-4 here; a truncated SVT would be off by
-        # whole singular values (~10).
+        # The exact path and svt_gram both form X (V c V^T) with the same
+        # projector, one batched and one not, so only the order in which a
+        # backend sums their matmuls may differ; that f32 error scales with
+        # |x|.  x is 10x the unit data of the cold-start test, so its atol of
+        # 2e-5 becomes 2e-4 here; a truncated SVT would be off by whole
+        # singular values (~10).
         np.testing.assert_allclose(warm.low_rank, svt_gram(x, 0.1), atol=2e-4)
 
     def test_rejects_non_2d(self, rng):
