@@ -57,6 +57,8 @@ class ModelConfig:
     mrope: bool = False  # qwen2-vl multimodal 3-axis RoPE
     mrope_sections: Tuple[int, ...] = (16, 24, 24)  # per-axis rotary dims (halves)
     logit_softcap: float = 0.0  # gemma-style final logit soft-capping (0 = off)
+    use_rope: bool = True  # False: no positional encoding (granite-4.0-h "nope")
+    attention_multiplier: float = 0.0  # softmax scale; 0 -> 1/sqrt(head_dim)
     # --- ffn ---
     ffn_kind: str = "swiglu"  # swiglu | geglu | gelu (0 d_ff -> no ffn)
     # --- moe ---
@@ -64,6 +66,14 @@ class ModelConfig:
     top_k: int = 0
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01  # load-balance loss weight
+    # Expert parallelism: experts_held > 0 makes every MoE layer the share of
+    # one chip (moe.apply_expert_share), holding experts [expert_offset,
+    # expert_offset + experts_held) of the n_experts the router spans.  The
+    # share is dropless, carries LoRA on each held expert's two linears, and
+    # adds a shared SwiGLU expert of width shared_d_ff (0: none).
+    experts_held: int = 0
+    expert_offset: int = 0
+    shared_d_ff: int = 0
     # --- ssm (mamba2) ---
     ssm_state: int = 0
     ssm_head_dim: int = 64
@@ -84,6 +94,11 @@ class ModelConfig:
     norm_eps: float = 1e-6
     tie_embeddings: bool = True
     embed_scale: bool = False  # gemma multiplies embeddings by sqrt(d_model)
+    # granite-4.0-h scalars: x = embed * embedding_multiplier; each residual
+    # branch is added times residual_multiplier; logits / logits_scaling.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     # --- lora ---
     lora: LoRAConfig = field(default_factory=LoRAConfig)
     # --- serving ---
@@ -145,6 +160,9 @@ class ModelConfig:
             vocab_size=min(self.vocab_size, 512),
             window_size=min(self.window_size, 32),
             n_experts=min(self.n_experts, 4),
+            experts_held=min(self.experts_held, 2),
+            expert_offset=0,
+            shared_d_ff=min(self.shared_d_ff, 512),
             top_k=min(self.top_k, 2) if self.top_k else 0,
             ssm_state=min(self.ssm_state, 32),
             ssm_head_dim=16 if self.ssm_state else self.ssm_head_dim,

@@ -32,9 +32,12 @@ _ARCH_MODULES = {
     "granite-moe-1b-a400m": "granite_moe_1b_a400m",
     "gemma-7b": "gemma_7b",
     "paper-vit-b32": "paper_vit_b32",
+    "granite-4.0-h-small": "granite_4_0_h_small",
 }
 
-ARCH_IDS = tuple(k for k in _ARCH_MODULES if k != "paper-vit-b32")
+# The assigned architectures; the paper's ViT and the benchmark's
+# granite-4.0-h-small are registered beside them.
+ARCH_IDS = tuple(k for k in _ARCH_MODULES if k not in ("paper-vit-b32", "granite-4.0-h-small"))
 
 
 def get_config(arch_id: str) -> ModelConfig:

@@ -28,11 +28,18 @@ from repro.core import AggregatorConfig, aggregate
 from repro.core import engine as engine_lib
 from repro.core.aggregators import CARRY_MODES, rpca_diag_summary
 from repro.models import model as model_lib
+from repro.models import moe
 from repro.utils.pytree import tree_add, tree_scale
 
 PyTree = Any
 
 _EXTRA_KEYS = ("vision_embeds", "encoder_frames", "positions")
+
+
+def _reduce(stats: dict) -> dict:
+    """Expert counters (``moe.COUNTERS``) stacked on leading axes -> one
+    scalar each."""
+    return {k: moe.COUNTERS[k][1](v) for k, v in stats.items()}
 
 
 def make_local_step(
@@ -55,14 +62,21 @@ def make_local_step(
     so the async pipeline can dispatch it against a global that is still
     missing the in-flight round's update.
 
+    An expert-share model (``cfg.experts_held``) returns a fourth output,
+    the expert layer's counters over every layer, local step and client:
+    ``moe_load_max``, the busiest held expert over the held mean, the worst.
+
     ``microbatch`` > 1 splits each client's batch into that many slices and
     accumulates LoRA grads over a scan — activation residency drops by the
     same factor (the llama4 §Perf fit fix) at no extra FLOPs.
     """
 
+    counters = tuple(moe.COUNTERS) if cfg.experts_held else ()
+
     def client_update(base, lora_global, client_batch):
         def full_loss(l, b):
-            return model_lib.loss_fn(base, l, b, cfg, remat=remat)[0]
+            total, parts = model_lib.loss_fn(base, l, b, cfg, remat=remat)
+            return total, {k: parts[k] for k in counters}
 
         if microbatch > 1:
             def local_loss_grad(l, b):
@@ -75,22 +89,19 @@ def make_local_step(
 
                 def acc(carry, mb_i):
                     loss_acc, g_acc = carry
-                    loss_i, g_i = jax.value_and_grad(full_loss)(l, mb_i)
+                    (loss_i, st_i), g_i = jax.value_and_grad(full_loss, has_aux=True)(l, mb_i)
                     g_acc = jax.tree_util.tree_map(lambda a, gi: a + gi, g_acc, g_i)
-                    return (loss_acc + loss_i, g_acc), None
+                    return (loss_acc + loss_i, g_acc), st_i
 
                 zeros = jax.tree_util.tree_map(
                     lambda x: jnp.zeros(x.shape, jnp.float32), l
                 )
-                (loss, g), _ = jax.lax.scan(acc, (jnp.zeros(()), zeros), mb)
+                (loss, g), st = jax.lax.scan(acc, (jnp.zeros(()), zeros), mb)
                 inv = 1.0 / microbatch
-                return loss * inv, jax.tree_util.tree_map(lambda x: x * inv, g)
+                return (loss * inv, _reduce(st)), jax.tree_util.tree_map(lambda x: x * inv, g)
         else:
             def local_loss_grad(l, b):
-                return jax.value_and_grad(full_loss)(l, b)
-
-        def local_loss(l, b):  # kept for the adam scan below
-            return full_loss(l, b)
+                return jax.value_and_grad(full_loss, has_aux=True)(l, b)
 
         if local_optimizer == "adam":
             from repro.optim import adam
@@ -103,26 +114,26 @@ def make_local_step(
             def one(carry, _):
                 lora, state = carry
                 with jax.named_scope("local.grad"):
-                    loss, g = local_loss_grad(lora, client_batch)
+                    (loss, st), g = local_loss_grad(lora, client_batch)
                 with jax.named_scope("local.opt"):
                     upd, state = opt.update(g, state, lora)
-                    return (apply_updates(lora, upd), state), loss
+                    return (apply_updates(lora, upd), state), (loss, st)
 
-            (lora, _), losses = jax.lax.scan(
+            (lora, _), (losses, stats) = jax.lax.scan(
                 one, (lora_global, state), None, length=local_steps
             )
         else:
             # Plain SGD local steps.
             def one(lora, _):
                 with jax.named_scope("local.grad"):
-                    loss, g = local_loss_grad(lora, client_batch)
+                    (loss, st), g = local_loss_grad(lora, client_batch)
                 with jax.named_scope("local.opt"):
-                    return tree_add(lora, tree_scale(g, -local_lr)), loss
+                    return tree_add(lora, tree_scale(g, -local_lr)), (loss, st)
 
-            lora, losses = jax.lax.scan(one, lora_global, None, length=local_steps)
+            lora, (losses, stats) = jax.lax.scan(one, lora_global, None, length=local_steps)
         with jax.named_scope("local.delta"):
             delta = jax.tree_util.tree_map(lambda a, b: a - b, lora, lora_global)
-            return delta, losses[-1]
+            return delta, losses[-1], _reduce(stats)
 
     def local_step(base, lora_global, batch, agg_key=None):
         extras = {k: batch[k] for k in _EXTRA_KEYS if k in batch}
@@ -145,7 +156,7 @@ def make_local_step(
             return client_update(base, lora_global, b)
 
         if mask is None:
-            deltas, losses = jax.vmap(client_fn)(
+            deltas, losses, stats = jax.vmap(client_fn)(
                 batch["tokens"], batch["labels"], *extras.values()
             )
         else:
@@ -157,18 +168,19 @@ def make_local_step(
             # with a scalar predicate skips the branch outright.
             def gated_fn(active, tokens, labels, *extra_vals):
                 def run(_):
-                    delta, loss = client_fn(tokens, labels, *extra_vals)
-                    return delta, loss.astype(jnp.float32)
+                    delta, loss, st = client_fn(tokens, labels, *extra_vals)
+                    return delta, loss.astype(jnp.float32), st
 
                 def skip(_):
                     return (
                         jax.tree_util.tree_map(jnp.zeros_like, lora_global),
                         jnp.zeros((), jnp.float32),
+                        {k: jnp.zeros((), jnp.float32) for k in counters},
                     )
 
                 return jax.lax.cond(active > 0, run, skip, None)
 
-            deltas, losses = jax.vmap(gated_fn)(
+            deltas, losses, stats = jax.vmap(gated_fn)(
                 mask, batch["tokens"], batch["labels"], *extras.values()
             )
         with jax.named_scope("local.delta"):
@@ -176,6 +188,8 @@ def make_local_step(
                 loss = jnp.mean(losses)
             else:
                 loss = jnp.sum(mask * losses) / jnp.maximum(jnp.sum(mask), 1.0)
+        if counters:
+            return deltas, loss, mask, _reduce(stats)
         return deltas, loss, mask
 
     return local_step
@@ -346,7 +360,8 @@ def make_fed_train_step(
     agg_step = make_agg_step(agg_cfg, engine=engine, client_weights=client_weights)
 
     def fed_train_step(base, lora_global, batch, agg_key=None, agg_carry=None):
-        deltas, loss, mask = local_step(base, lora_global, batch, agg_key)
+        # An expert share's counters, a fourth output, are not reported here.
+        deltas, loss, mask = local_step(base, lora_global, batch, agg_key)[:3]
         if agg_step.carry_on:
             upd, metrics, new_carry = agg_step(deltas, mask, agg_key, agg_carry)
             return apply_update(lora_global, upd), {"loss": loss, **metrics}, new_carry

@@ -424,7 +424,8 @@ def main(argv=None):
             np.random.default_rng((args.seed, 1000 + r)),
         )
         round_key = jax.random.fold_in(key, 1000 + r)
-        deltas, loss, mask = local_step(base, state.lora_global, batch, round_key)
+        # An expert share's counters, a fourth output, are not logged.
+        deltas, loss, mask = local_step(base, state.lora_global, batch, round_key)[:3]
         if rank_masks is not None:
             # Zero each client's delta beyond its declared rank — what a
             # rank-r_i client would actually have trained and shipped.

@@ -80,9 +80,11 @@ def naive_attention(
     window: int = 0,
     q_offset=0,
     kv_len: Optional[jnp.ndarray] = None,
+    scale: float = 0.0,
 ) -> jnp.ndarray:
-    """Materialized-score attention (small shapes only)."""
-    scale = 1.0 / math.sqrt(q.shape[-1])
+    """Materialized-score attention (small shapes only).  ``scale`` of the
+    scores: 0 -> 1/sqrt(head_dim)."""
+    scale = scale or 1.0 / math.sqrt(q.shape[-1])
     scores = jnp.einsum("bqhgd,bshd->bhgqs", q, k).astype(jnp.float32) * scale
     sq, sk = q.shape[1], k.shape[1]
     q_pos = q_offset + jnp.arange(sq)
@@ -118,11 +120,12 @@ def flash_attention(
     q_offset: int = 0,
     block_q: int = 512,
     block_k: int = 512,
+    scale: float = 0.0,
 ) -> jnp.ndarray:
     """Blockwise online-softmax attention (jnp flash; mesh execution path)."""
     b, sq, n_kv, g, d = q.shape
     sk = k.shape[1]
-    scale = 1.0 / math.sqrt(d)
+    scale = scale or 1.0 / math.sqrt(d)
 
     pad_q = (-sq) % block_q
     pad_k = (-sk) % block_k
@@ -213,6 +216,7 @@ def decode_attention(
     *,
     window: int = 0,
     ring: bool = False,
+    scale: float = 0.0,
 ) -> jnp.ndarray:
     """Single-token attention against a cache.
 
@@ -221,7 +225,7 @@ def decode_attention(
     relative recency is irrelevant to softmax, so no positional mask is
     needed beyond validity.
     """
-    scale = 1.0 / math.sqrt(q.shape[-1])
+    scale = scale or 1.0 / math.sqrt(q.shape[-1])
     scores = jnp.einsum("bqhgd,bshd->bhgqs", q, k_cache).astype(jnp.float32) * scale
     s = k_cache.shape[1]
     pos = jnp.arange(s)
@@ -267,7 +271,8 @@ def apply_attention(
 
     if is_cross and cache is not None:
         # Cached cross-attention: encoder K/V were projected once at prefill.
-        out = naive_attention(q, cache.k.astype(q.dtype), cache.v.astype(q.dtype), causal=False)
+        out = naive_attention(q, cache.k.astype(q.dtype), cache.v.astype(q.dtype),
+                              causal=False, scale=cfg.attention_multiplier)
         out = _merge_heads(out)
         return layers.dense(out, params["o"], lora.get("o"), scale), cache
 
@@ -321,13 +326,16 @@ def apply_attention(
         ring = bool(window)
         cache_len = jnp.minimum(total, k_cache.shape[1]) if ring else total
         out = decode_attention(
-            q, k_cache, v_cache, jnp.full((b,), cache_len), window=window, ring=ring
+            q, k_cache, v_cache, jnp.full((b,), cache_len), window=window, ring=ring,
+            scale=cfg.attention_multiplier,
         )
     else:
         if max(sq, k.shape[1]) >= FLASH_THRESHOLD:
-            out = flash_attention(q, k, v, causal=causal, window=window)
+            out = flash_attention(q, k, v, causal=causal, window=window,
+                                  scale=cfg.attention_multiplier)
         else:
-            out = naive_attention(q, k, v, causal=causal, window=window)
+            out = naive_attention(q, k, v, causal=causal, window=window,
+                                  scale=cfg.attention_multiplier)
         if return_cache:
             if window and k.shape[1] >= window:
                 # Ring layout: decode writes token t at slot t % window, so

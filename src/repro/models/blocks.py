@@ -39,7 +39,9 @@ def init_block(key, cfg, mixer_kind: str, dtype, *, cross: bool = False):
         p["cross"] = attention.init_attention(k_cross, cfg, dtype)
     if cfg.d_ff > 0:
         p["norm2"] = layers.init_norm(cfg.norm_kind, cfg.d_model, jnp.float32)
-        if cfg.n_experts > 0:
+        if cfg.experts_held:
+            p["moe"] = moe.init_expert_share(k_ffn, cfg, dtype)
+        elif cfg.n_experts > 0:
             p["moe"] = moe.init_moe(k_ffn, cfg.d_model, cfg.d_ff, cfg.n_experts, dtype)
         else:
             p["ffn"] = ffn.init_ffn(k_ffn, cfg.d_model, cfg.d_ff, cfg.ffn_kind, dtype)
@@ -48,7 +50,8 @@ def init_block(key, cfg, mixer_kind: str, dtype, *, cross: bool = False):
 
 def init_block_lora(key, cfg, mixer_kind: str, dtype, *, cross: bool = False):
     """LoRA adapters for a block — attention Q/V (paper setting) or, for
-    attention-free mixers, the mixer's in/out projections."""
+    attention-free mixers, the mixer's in/out projections; in an expert share
+    (``cfg.experts_held``) also each held expert's input and output linears."""
     k_mix, k_cross = jax.random.split(key)
     lora: dict = {}
     if mixer_kind in ATTN_KINDS:
@@ -70,7 +73,28 @@ def init_block_lora(key, cfg, mixer_kind: str, dtype, *, cross: bool = False):
         }
     if cross:
         lora["cross"] = attention.init_attention_lora(k_cross, cfg, dtype)
+    if cfg.experts_held:
+        lora["moe"] = moe.init_expert_lora(jax.random.fold_in(key, 2), cfg, dtype)
     return lora
+
+
+def zero_stats(cfg) -> dict:
+    """What a block reports beside its output, summed over the stack:
+    ``aux`` (the capacity-bounded MoE's load-balance loss) and, for an
+    expert share, its counters (``moe.COUNTERS``)."""
+    keys = ("aux", *moe.COUNTERS) if cfg.experts_held else ("aux",)
+    return {k: jnp.zeros((), jnp.float32) for k in keys}
+
+
+def add_stats(a: dict, b: dict) -> dict:
+    return {k: moe.COUNTERS[k][0](a[k], b[k]) if k in moe.COUNTERS else a[k] + b[k]
+            for k in a}
+
+
+def _residual(x, out, cfg):
+    if cfg.residual_multiplier != 1.0:
+        out = out * jnp.asarray(cfg.residual_multiplier, out.dtype)
+    return x + out
 
 
 def apply_block(
@@ -88,9 +112,9 @@ def apply_block(
     use_rope: bool = True,
     causal: bool = True,
 ) -> Tuple[jnp.ndarray, Any, jnp.ndarray]:
-    """Returns (x, new_cache, moe_aux_loss)."""
+    """Returns (x, new_cache, stats) with ``stats`` as ``zero_stats(cfg)``."""
     lora = lora or {}
-    aux = jnp.zeros((), jnp.float32)
+    stats = zero_stats(cfg)
     h = layers.apply_norm(params["norm1"], x, cfg.norm_eps)
     window = cfg.window_size if mixer_kind == "local_attn" else 0
     prefill = mode == "prefill"
@@ -112,10 +136,11 @@ def apply_block(
         )
     elif mixer_kind == "ssd":
         state = cache["self"] if cache is not None else None
-        out, new_self = ssd.apply_ssd(
-            params["mixer"], lora.get("mixer"), h, cfg,
-            state=state, lora_scale=cfg.lora.scale, return_state=prefill,
-        )
+        with jax.named_scope("local.ssd"):
+            out, new_self = ssd.apply_ssd(
+                params["mixer"], lora.get("mixer"), h, cfg,
+                state=state, lora_scale=cfg.lora.scale, return_state=prefill,
+            )
     elif mixer_kind == "rglru":
         state = cache["self"] if cache is not None else None
         out, new_self = rglru.apply_rglru(
@@ -124,7 +149,7 @@ def apply_block(
         )
     else:
         raise ValueError(mixer_kind)
-    x = x + out
+    x = _residual(x, out, cfg)
 
     new_cross = None
     if "cross" in params:
@@ -148,13 +173,17 @@ def apply_block(
 
     if "ffn" in params:
         h2 = layers.apply_norm(params["norm2"], x, cfg.norm_eps)
-        x = x + ffn.apply_ffn(params["ffn"], h2, cfg.ffn_kind)
+        x = _residual(x, ffn.apply_ffn(params["ffn"], h2, cfg.ffn_kind), cfg)
     elif "moe" in params:
         h2 = layers.apply_norm(params["norm2"], x, cfg.norm_eps)
-        out, aux = moe.apply_moe(
-            params["moe"], h2, top_k=cfg.top_k, capacity_factor=cfg.capacity_factor
-        )
-        x = x + out
+        if cfg.experts_held:
+            out, counts = moe.apply_expert_share(params["moe"], lora.get("moe"), h2, cfg)
+            stats.update(counts)
+        else:
+            out, stats["aux"] = moe.apply_moe(
+                params["moe"], h2, top_k=cfg.top_k, capacity_factor=cfg.capacity_factor
+            )
+        x = _residual(x, out, cfg)
 
     new_cache = cache
     if mode in ("prefill", "decode"):
@@ -163,7 +192,7 @@ def apply_block(
             new_cache["cross"] = new_cross if new_cross is not None else (
                 cache.get("cross") if cache else None
             )
-    return x, new_cache, aux
+    return x, new_cache, stats
 
 
 def _encoder_kv(cross_params, cross_lora, encoder_out, cfg) -> KVCache:

@@ -6,8 +6,8 @@ layer ``i`` live at pattern slot ``i % unit`` with a leading *group* axis of
 size ``n_layers // unit``; layers that don't fill a whole unit sit unstacked
 in ``tail``.  The forward pass is a ``lax.scan`` over groups (+ explicit tail)
 so HLO size is O(unit), independent of depth — this is what makes the
-95-layer deepseek-67b dry-run compile quickly.  Train mode wraps the scan
-body in ``jax.checkpoint`` (remat).
+95-layer deepseek-67b dry-run compile quickly.  Train mode with remat wraps
+each block in ``jax.checkpoint``.
 
 Modes:
   train    — full-sequence forward, logits for next-token loss.
@@ -16,6 +16,7 @@ Modes:
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Optional, Tuple
 
@@ -132,6 +133,8 @@ def _embed_inputs(params, batch, cfg, mode: str, cache_index):
     x = jnp.take(params["embed"], tokens, axis=0)
     if cfg.embed_scale:
         x = x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
     if cfg.frontend == "vision" and "vision_embeds" in batch and mode != "decode":
         ve = batch["vision_embeds"].astype(x.dtype)  # (B, n_vis, D)
         x = jax.lax.dynamic_update_slice(x, ve, (0, 0, 0))
@@ -178,8 +181,26 @@ def _run_stack(
     lora_groups = lora["groups"] if lora else tuple({} for _ in range(unit))
     lora_tail = lora["tail"] if lora else tuple({} for _ in params.get("tail", ()))
 
+    def block(p, l, x, c, kind):
+        run = functools.partial(
+            blocks.apply_block,
+            cfg=cfg,
+            mixer_kind=kind,
+            positions=positions,
+            mode=mode,
+            cache_index=cache_index,
+            encoder_out=encoder_out,
+            use_rope=use_rope,
+            causal=causal,
+        )
+        if remat and mode == "train":
+            # Per block: a group of several layers then keeps only its
+            # blocks' inputs for the backward pass, not all its activations.
+            run = jax.checkpoint(run)
+        return run(p, l, x, cache=c)
+
     def group_body(carry, xs):
-        x, aux = carry
+        x, stats = carry
         if caches is None:
             p_slots, l_slots = xs
             c_slots = (None,) * unit
@@ -187,57 +208,29 @@ def _run_stack(
             p_slots, l_slots, c_slots = xs
         new_cs = []
         for i, kind in enumerate(pattern):
-            x, nc, a = blocks.apply_block(
-                p_slots[i],
-                l_slots[i],
-                x,
-                cfg,
-                kind,
-                positions=positions,
-                mode=mode,
-                cache=c_slots[i],
-                cache_index=cache_index,
-                encoder_out=encoder_out,
-                use_rope=use_rope,
-                causal=causal,
-            )
+            x, nc, a = block(p_slots[i], l_slots[i], x, c_slots[i], kind)
             new_cs.append(nc)
-            aux = aux + a
+            stats = blocks.add_stats(stats, a)
         ys = tuple(new_cs) if mode in ("prefill", "decode") else None
-        return (x, aux), ys
-
-    body = jax.checkpoint(group_body) if (remat and mode == "train") else group_body
+        return (x, stats), ys
 
     xs = (params["groups"], lora_groups)
     if caches is not None:
         xs = xs + (caches["groups"],)
-    (x, aux), group_caches = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), xs)
+    (x, stats), group_caches = jax.lax.scan(group_body, (x, blocks.zero_stats(cfg)), xs)
 
     tail_caches = []
     for i, p in enumerate(params.get("tail", ())):
         kind = pattern[i % unit]
         c = caches["tail"][i] if caches is not None else None
-        x, nc, a = blocks.apply_block(
-            p,
-            lora_tail[i] if lora_tail else {},
-            x,
-            cfg,
-            kind,
-            positions=positions,
-            mode=mode,
-            cache=c,
-            cache_index=cache_index,
-            encoder_out=encoder_out,
-            use_rope=use_rope,
-            causal=causal,
-        )
+        x, nc, a = block(p, lora_tail[i] if lora_tail else {}, x, c, kind)
         tail_caches.append(nc)
-        aux = aux + a
+        stats = blocks.add_stats(stats, a)
 
     new_caches = None
     if mode in ("prefill", "decode"):
         new_caches = {"groups": group_caches, "tail": tuple(tail_caches)}
-    return x, new_caches, aux
+    return x, new_caches, stats
 
 
 def encode(params, batch, cfg) -> jnp.ndarray:
@@ -276,15 +269,18 @@ def forward(
     cache_index=None,
     remat: bool = True,
 ) -> Tuple[jnp.ndarray, Optional[PyTree], jnp.ndarray]:
-    """Returns (logits, new_caches, moe_aux_loss)."""
+    """Returns (logits, new_caches, stats): ``stats`` sums the blocks'
+    ``aux`` load-balance losses and an expert share's counters
+    (``blocks.zero_stats``)."""
     x, positions = _embed_inputs(params, batch, cfg, mode, cache_index)
 
     encoder_out = None
     if cfg.encoder_decoder and mode != "decode":
         encoder_out = encode(params, batch, cfg)
 
-    use_rope = not cfg.encoder_decoder  # whisper: learned absolute positions
-    x, new_caches, aux = _run_stack(
+    # whisper: learned absolute positions; granite-4.0-h: none at all.
+    use_rope = cfg.use_rope and not cfg.encoder_decoder
+    x, new_caches, stats = _run_stack(
         x,
         params,
         lora,
@@ -307,7 +303,9 @@ def forward(
     head = params["lm_head"] if "lm_head" in params else params["embed"].T
     logits = jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype))
     logits = layers.softcap(logits.astype(jnp.float32), cfg.logit_softcap)
-    return logits, new_caches, aux
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits, new_caches, stats
 
 
 def loss_fn(params, lora, batch, cfg, *, remat: bool = True) -> Tuple[jnp.ndarray, dict]:
@@ -319,7 +317,7 @@ def loss_fn(params, lora, batch, cfg, *, remat: bool = True) -> Tuple[jnp.ndarra
     tensor per chip (measured +20 GiB on llama4 train; EXPERIMENTS.md §Perf).
     The select+reduce fuses and only the (B, S) partials cross shards.
     """
-    logits, _, aux = forward(params, lora, batch, cfg, mode="train", remat=remat)
+    logits, _, stats = forward(params, lora, batch, cfg, mode="train", remat=remat)
     labels = batch["labels"]
     mask = (labels >= 0).astype(jnp.float32)
     labels_safe = jnp.maximum(labels, 0)
@@ -330,8 +328,8 @@ def loss_fn(params, lora, batch, cfg, *, remat: bool = True) -> Tuple[jnp.ndarra
     true_logit = jnp.sum(jnp.where(label_hit, logits, 0.0), axis=-1)
     nll = lse - true_logit
     loss = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
-    total = loss + cfg.router_aux_weight * aux
-    return total, {"ce": loss, "aux": aux}
+    total = loss + cfg.router_aux_weight * stats["aux"]
+    return total, {"ce": loss, **stats}
 
 
 # ---------------------------------------------------------------------------

@@ -236,7 +236,7 @@ def apply_ssd(
 
     y = jnp.reshape(y, (*y.shape[:2], dims["d_inner"])).astype(x.dtype)
     # Gated RMSNorm (mamba2): norm(y * silu(z))
-    y = layers.apply_norm(params["norm"], y * jax.nn.silu(z))
+    y = layers.apply_norm(params["norm"], y * jax.nn.silu(z), cfg.norm_eps)
     out = layers.dense(y, params["out_proj"], lora.get("v"), lora_scale)
     return out, new_state
 

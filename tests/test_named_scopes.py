@@ -95,3 +95,21 @@ def test_svt_steps_counts_the_steps_each_carried_tier_ran(fixed):
     else:
         assert 1 <= steps <= cfg.rpca_iters * tiers
     assert 1 <= falls <= steps  # a cold start falls back at least once
+
+
+def test_hybrid_local_step_scopes_and_counters():
+    """The Mamba-2 mixers and the expert layer carry their scopes into the
+    compiled local step, which returns the expert counters beside the loss."""
+    cfg = cfglib.get_config("granite-4.0-h-small").reduced()
+    key = jax.random.PRNGKey(0)
+    base, lora = init_params(key, cfg), init_lora_params(key, cfg)
+    batch = {"tokens": jnp.zeros((2, 1, 32), jnp.int32),
+             "labels": jnp.zeros((2, 1, 32), jnp.int32)}
+    step = jax.jit(steps_lib.make_local_step(cfg, local_lr=1e-3, local_steps=2,
+                                             local_optimizer="adam"))
+    text = step.lower(base, lora, batch).compile().as_text()
+    assert {"local.ssd", "local.moe.route", "local.moe.experts",
+            "local.moe.shared"} <= scopes(text)
+    assert any("local.grad/transpose(" in p and "local.ssd" in p for p in op_names(text))
+    _, _, _, counts = step(base, lora, batch)
+    assert set(counts) == {"moe_load_max"} and float(counts["moe_load_max"]) >= 1.0
