@@ -15,8 +15,9 @@ One chip (no arguments):
 
 ``--four-chips``: training with the aggregation sharded over four chips
 (``--mesh-shards 4 --rpca-fused-tail``, 2 rounds), then the sharded fused
-aggregation (real deltas) and decomposition (both inputs) against the
-single-device ones on chip 0.
+subspace aggregation (real deltas) and decomposition (both inputs), and the
+sharded gram ones with the XLA tail, against the single-device ones on
+chip 0.
 
 Real deltas have live rank 8 = the cohort, so every ADMM iteration takes the
 exact-eigh SVT.  The planted deltas (rank 2 + 1% spikes) let the subspace
@@ -322,6 +323,15 @@ def sharded_parity(smoke: Smoke, inputs: dict) -> None:
     runs.compare("sharded-LS", "one-chip-LS", TOL_SHARDED_DECOMP)
     runs.took_ritz_path("one-chip-LS")
     runs.took_ritz_path("sharded-LS")
+    # The gram loop shards the vec rows and exchanges (B, C, C) Grams.
+    gram = cfg.replace(svt_mode="gram", rpca_fused_tail=False)
+    runs("gram/one-chip", aggregation(gram, engine="packed"), only="real")
+    hlo = runs("gram/sharded", aggregation(gram, engine="packed", mesh=mesh), only="real")
+    smoke.check("all-gather" in hlo, "the sharded gram aggregation has no all-gather")
+    runs("gram/one-chip-LS", bucket_decomposition("gram", False), unit=True)
+    runs("gram/sharded-LS", bucket_decomposition("gram", False, mesh), unit=True)
+    runs.compare("gram/sharded", "gram/one-chip", TOL_SHARDED_AGG)
+    runs.compare("gram/sharded-LS", "gram/one-chip-LS", TOL_SHARDED_DECOMP)
 
 
 def main(argv=None) -> int:

@@ -449,8 +449,9 @@ def _fedrpca_bucket(
     ``svt_rank`` overrides the config's basis-width cap — the two-tier
     re-pack runs converged tiers at a tighter cap.  ``mesh`` (multi-shard)
     routes the ADMM loop through ``robust_pca_bucket_sharded``; the
-    column-mean tail stays a plain einsum (GSPMD partitions it along the
-    constraint ``pack`` placed on the bucket).
+    column-mean tail stays a plain einsum, which GSPMD partitions along the
+    loop's output layout (client columns in subspace mode, vec rows in gram
+    mode, where the client means are shard-local).
 
     ``uplink`` (an active ``fed.sketch.UplinkConfig``, carry required)
     replaces the dense client columns with their sketch round-trip —
@@ -503,7 +504,8 @@ def _fedrpca_bucket(
         m = m * (bucket.weights * n_eff)[None, None, :]
     rpca_fn = rpca_lib.robust_pca_bucket
     rpca_kwargs = {}
-    if mesh is not None and rpca_lib.mesh_client_shards(mesh) > 1:
+    sharded = mesh is not None and rpca_lib.mesh_client_shards(mesh) > 1
+    if sharded:
         rpca_fn = rpca_lib.robust_pca_bucket_sharded
         rpca_kwargs = {"mesh": mesh, "mesh_overlap": cfg.mesh_overlap}
     res = rpca_fn(
@@ -560,6 +562,12 @@ def _fedrpca_bucket(
         else:
             beta = jnp.full(energy.shape, cfg.beta, jnp.float32)
         update = low_mean + beta[:, None] * sparse_mean
+        if sharded:
+            # The gram loop leaves L and S row-sharded: replicate the
+            # (B, vec) update here, once, and not leaf by leaf in unpack.
+            from jax.sharding import NamedSharding, PartitionSpec as P
+
+            update = jax.lax.with_sharding_constraint(update, NamedSharding(mesh, P()))
         diag = {
             "beta": beta, "energy": energy, "residual": res.residual,
             "n_iter": res.n_iter, **diag_extra, **uplink_diag,

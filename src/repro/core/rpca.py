@@ -1066,11 +1066,15 @@ def robust_pca_bucket(
 # Mesh-sharded bucket RPCA (DESIGN.md §10)
 # ---------------------------------------------------------------------------
 #
-# The packed client axis (d2) of a bucket is the axis that scales — cohorts
-# grow, vec dims don't — so the sharded loop splits client COLUMNS across
-# the mesh's client axes ("pod", "data").  Everything elementwise (shrink,
-# dual ascent, masking) is column-local and runs on the shard untouched.
-# The subspace SVT decomposes around the projected factor W = X @ V:
+# The sharded loop splits a bucket over the mesh's client axes ("pod",
+# "data"); everything elementwise (shrink, dual ascent, masking) runs on the
+# shard untouched.  The gram SVT splits the vec ROWS (d1): G = sum_k X_k^T
+# X_k, so an iteration reduce-scatters the (B, C, C) Gram partials over B,
+# each shard eighs its B / shards modules, and the shrink projectors are
+# all-gathered back for the shard-local L_k = X_k @ P.
+#
+# The subspace SVT splits the client COLUMNS (d2) and decomposes around
+# the projected factor W = X @ V:
 #
 #   W      = psum_k( X_k @ V_k )         one (B, d1, r) all-reduce per sweep
 #   (GV)_k = X_k^T @ W                   shard-local rows of G @ V
@@ -1108,6 +1112,224 @@ def mesh_client_shards(mesh) -> int:
     return n
 
 
+def _shard_index(mesh):
+    """This shard's position along the mesh's client axes, inside
+    ``shard_map``: the order of a tiled collective over them."""
+    idx = jnp.zeros((), jnp.int32)
+    for a in mesh_client_axes(mesh):
+        idx = idx * mesh.shape[a] + jax.lax.axis_index(a)
+    return idx
+
+
+def _gram_rows_sharded(
+    m, dims_f, *, mesh, n_iter, tol, mu, lam, shrink_fn, fused_tail, interpret,
+    client_mask, r, carry, return_carry, carry_gate, mesh_overlap,
+):
+    """The gram path of ``robust_pca_bucket_sharded`` on a float32 bucket:
+    its vec axis ``d1`` split over the mesh's client axes, every client on
+    every shard (DESIGN.md §10).  Returns ``(L, S, n_iter, err)`` and, with
+    ``return_carry``, the exit ``BucketCarry``."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    axes = mesh_client_axes(mesh)
+    ax = axes if len(axes) > 1 else axes[0]
+    shards = mesh_client_shards(mesh)
+    b, d1p, d2 = m.shape
+    cmask = (
+        jnp.ones((d2,), jnp.float32)
+        if client_mask is None
+        else jnp.asarray(client_mask, jnp.float32)
+    )
+    # Zero rows (to a shard multiple of d1) and zero modules (to a shard
+    # multiple of B, Grams only) are inert: they add nothing to a Gram or a
+    # norm, and a zero Gram shrinks to a zero projector (DESIGN.md §1, §3).
+    d1s = shards * (-(-d1p // shards))
+    b_loc = -(-b // shards)
+    row = P(None, ax, None)
+    rep = P()
+
+    def to_rows(t):
+        # The one layout change of the call: buckets arrive with their
+        # client columns sharded (``pack``) and enter the loop row-sharded.
+        t = jnp.pad(t, ((0, 0), (0, d1s - d1p), (0, 0)))
+        with jax.named_scope("agg.psum"):
+            return jax.lax.with_sharding_constraint(t, NamedSharding(mesh, row))
+
+    m = to_rows(m)
+    has_carry = carry is not None
+    if has_carry:
+        carry = carry._replace(l=to_rows(carry.l), s=to_rows(carry.s),
+                               y=to_rows(carry.y))
+    carry_spec = BucketCarry(
+        l=row, s=row, y=row, v=rep, n_live=rep, n_eff=rep, valid=rep,
+        fall_count=rep, hit=rep,
+    )
+    if fused_tail:
+        from repro.kernels import rpca_admm as _tail_kernel
+        from repro.kernels.backend import resolve_interpret
+
+        interp = resolve_interpret(interpret)
+
+    def inner(m_k, dims_f, cmask, *rest):
+        def gs(x):
+            with jax.named_scope("agg.psum"):
+                return jax.lax.psum(x, ax)
+
+        m_k = m_k * cmask
+        # The mask is replicated: n_eff is already the whole cohort's.
+        n_eff = jnp.maximum(jnp.sum(cmask), 1.0)
+        abs_sum = gs(jnp.sum(jnp.abs(m_k), axis=(1, 2)))
+        numel = dims_f * n_eff
+        mu_v = jnp.where(
+            abs_sum > _EPS, numel / (4.0 * jnp.maximum(abs_sum, _EPS)), 1.0
+        )
+        if mu is not None:
+            mu_v = jnp.full((b,), mu, jnp.float32)
+        lam_v = (
+            jnp.full((b,), lam, jnp.float32)
+            if lam is not None
+            else 1.0 / jnp.sqrt(jnp.maximum(dims_f, n_eff))
+        )
+        rho = 1.0 / mu_v
+        thresh = rho * lam_v
+        m_norm = jnp.maximum(jnp.sqrt(gs(jnp.sum(m_k * m_k, axis=(1, 2)))), _EPS)
+        rho_b = rho[:, None, None]
+        mu_b = mu_v[:, None, None]
+        # The SVT thresholds of this shard's modules of the reduce-scattered
+        # Grams (a padding module's Gram is zero, whatever its threshold).
+        rho_loc = jax.lax.dynamic_slice_in_dim(
+            jnp.pad(rho, (0, b_loc * shards - b), constant_values=1.0),
+            _shard_index(mesh) * b_loc, b_loc,
+        )
+
+        if has_carry:
+            cin = rest[0]
+            cl, cs, cy = cin.l * cmask, cin.s * cmask, cin.y * cmask
+            init_res = m_k - cl - cs
+            init_err = (
+                jnp.sqrt(gs(jnp.sum(init_res * init_res, axis=(1, 2)))) / m_norm
+            )
+            warm = jnp.logical_and(
+                jnp.asarray(cin.valid),
+                jnp.logical_and(cin.n_eff == n_eff, jnp.all(init_err <= carry_gate)),
+            )
+            wsel = lambda a: jnp.where(warm, a, 0.0)
+            l0, s0, y0 = wsel(cl), wsel(cs), wsel(cy)
+        else:
+            warm = jnp.asarray(False)
+            l0 = s0 = y0 = jnp.zeros_like(m_k)
+
+        # The fused tail's residual psum issues per B-chunk under the
+        # overlap knob, as in the client-sharded path.
+        bsl = [(0, b)]
+        if mesh_overlap and b > 1:
+            nch = min(b, _MESH_OVERLAP_CHUNKS)
+            step_b = -(-b // nch)
+            bsl = [(lo, min(lo + step_b, b)) for lo in range(0, b, step_b)]
+
+        @jax.named_scope("agg.svt")
+        def svt(x_k):
+            # G = sum over shards of X_k^T X_k: reduce-scatter the (B, C, C)
+            # partials over B, eigh this shard's modules, all-gather their
+            # projectors; L_k = X_k @ P stays on the shard.
+            g = jnp.einsum("bdc,bde->bce", x_k, x_k)
+            g = jnp.pad(g, ((0, b_loc * shards - b), (0, 0), (0, 0)))
+            with jax.named_scope("agg.psum"):
+                g = jax.lax.psum_scatter(g, ax, scatter_dimension=0, tiled=True)
+            p, _, _ = _shrink_projector(g, rho_loc[:, None], shrink_fn)
+            with jax.named_scope("agg.psum"):
+                p = jax.lax.all_gather(p, ax, axis=0, tiled=True)[:b]
+            return jnp.einsum("bdc,bce->bde", x_k, p)
+
+        def tail(l, y):
+            if fused_tail:
+                outs = [
+                    _tail_kernel.admm_tail(
+                        m_k[lo:hi], l[lo:hi], y[lo:hi], rho[lo:hi],
+                        mu_v[lo:hi], thresh[lo:hi], mask=cmask,
+                        interpret=interp,
+                    )
+                    for lo, hi in bsl
+                ]
+                s = jnp.concatenate([o[0] for o in outs], axis=0)
+                y_new = jnp.concatenate([o[1] for o in outs], axis=0)
+                rsq = jnp.concatenate([gs(o[2]) for o in outs], axis=0)
+                return s, y_new, jnp.sqrt(rsq)
+            s = shrink_fn(m_k - l + rho_b * y, thresh[:, None, None]) * cmask
+            resid = (m_k - l - s) * cmask
+            y_new = (y + mu_b * resid) * cmask
+            return s, y_new, jnp.sqrt(gs(jnp.sum(resid * resid, axis=(1, 2))))
+
+        def step(l, s, y):
+            # robust_pca_bucket's barrier: X @ P is not fused with the
+            # tail's M - L.
+            l = jax.lax.optimization_barrier(svt(m_k - s + rho_b * y))
+            s, y, rnorm = tail(l, y)
+            return l, s, y, rnorm / m_norm
+
+        err0 = jnp.full((b,), jnp.inf, jnp.float32)
+        if tol is None:
+            l, s, y, err = jax.lax.fori_loop(
+                0, n_iter, lambda _, st: step(*st[:3]), (l0, s0, y0, err0)
+            )
+            n_done = jnp.full((b,), n_iter, jnp.int32)
+        else:
+
+            def cond(state):
+                return jnp.logical_and(state[4] < n_iter, jnp.any(state[3] > tol))
+
+            def body(state):
+                l, s, y, err, i, niter = state
+                l2, s2, y2, err2 = step(l, s, y)
+                active = err > tol
+                sel = lambda new, old: jnp.where(active[:, None, None], new, old)
+                return (
+                    sel(l2, l), sel(s2, s), sel(y2, y),
+                    jnp.where(active, err2, err),
+                    i + 1, jnp.where(active, i + 1, niter),
+                )
+
+            init = (l0, s0, y0, err0, jnp.asarray(0, jnp.int32),
+                    jnp.zeros((b,), jnp.int32))
+            l, s, y, err, _, n_done = jax.lax.while_loop(cond, body, init)
+
+        l = l * cmask
+        outs = (l, s, n_done, err)
+        if not return_carry:
+            return outs
+        if has_carry:
+            v_out, nl_out = cin.v, cin.n_live
+        else:
+            # Gram mode has no basis to track; keep the slots shape-stable.
+            v_out = jnp.zeros((b, d2, r), jnp.float32)
+            nl_out = jnp.zeros((b,), jnp.int32)
+        new_carry = BucketCarry(
+            l=l, s=s, y=y, v=v_out, n_live=nl_out, n_eff=n_eff,
+            valid=jnp.ones((), bool), fall_count=jnp.zeros((), jnp.int32),
+            hit=warm.astype(jnp.float32),
+        )
+        return outs + (new_carry,)
+
+    in_specs = [row, rep, rep]
+    args = [m, dims_f, cmask]
+    if has_carry:
+        in_specs.append(carry_spec)
+        args.append(carry)
+    out_specs = (row, row, rep, rep)
+    if return_carry:
+        out_specs = out_specs + (carry_spec,)
+    out = jax.shard_map(
+        inner, mesh=mesh, in_specs=tuple(in_specs), out_specs=out_specs,
+        check_vma=False,
+    )(*args)
+    cut = lambda t: t[:, :d1p]
+    l, s, n_done, err = out[:4]
+    if not return_carry:
+        return cut(l), cut(s), n_done, err
+    c = out[4]
+    return cut(l), cut(s), n_done, err, c._replace(l=cut(c.l), s=cut(c.s), y=cut(c.y))
+
+
 @_f32_matmuls
 @jax.named_scope("agg.admm")
 def robust_pca_bucket_sharded(
@@ -1133,11 +1355,21 @@ def robust_pca_bucket_sharded(
     mesh_overlap: bool = False,
     true_cols: int | None = None,
 ) -> RPCAResult:
-    """``robust_pca_bucket`` with the client axis sharded across ``mesh``.
+    """``robust_pca_bucket`` sharded across ``mesh``'s client axes.
 
     Same contract as the single-device loop (fp32-allclose results, same
-    carry pytree with the eigenbasis rows client-sharded internally and
-    reassembled on exit).  One client shard (``mesh_client_shards(mesh) ==
+    carry pytree).  The two SVT modes shard different axes (DESIGN.md §10):
+
+    - ``svt_mode="gram"`` splits the bucket's vec axis ``d1``; every shard
+      holds every client.  A Gram is a sum over rows, so an ADMM iteration
+      exchanges only ``(B, C, C)``: the Gram partials are reduce-scattered
+      over the module axis B (each shard eighs ``B / shards`` modules, B
+      zero-padded to a shard multiple) and the shrink projectors
+      all-gathered back; ``L_k = X_k @ P``, the tail and the scalar norms'
+      partials stay on the shard.  The bucket's one layout change, from
+      ``pack``'s client columns to rows, happens once on entry.
+    - ``svt_mode="subspace"`` splits the client axis ``d2``, with the
+      eigenbasis rows client-sharded internally and reassembled on exit.  One client shard (``mesh_client_shards(mesh) ==
     1``, the ``(1, 1)`` debug mesh included) delegates to
     ``robust_pca_bucket`` — the single-device path stays bitwise identical.
 
@@ -1145,16 +1377,16 @@ def robust_pca_bucket_sharded(
     shard calls ``kernels.rpca_admm.admm_tail`` (exact-SVT iterations) or
     ``kernels.svt_subspace.subspace_apply_factored`` (Ritz iterations — the
     rank-r reconstruction ``L_k = F Vr_k^T`` fused with the elementwise
-    tail, no d2^2 projector ever materialized) on its own column slice with
-    the shard's mask slice, and only the scalar residual partials are
-    psum-reduced afterward.  The kernels stay single-device; sharding only
-    crosses in the reductions.
+    tail, no d2^2 projector ever materialized) on its own slice, and only
+    the scalar residual partials are psum-reduced afterward.  The kernels
+    stay single-device; sharding only crosses in the reductions.
 
-    Ragged cohorts (``d2 % shards != 0``) are accepted: the bucket is
-    zero-padded to the next shard multiple with zero-mask columns threaded
-    through pack/psum/tail, so padded columns contribute exactly zero to
-    every reduction, ``n_eff`` stays the true active count, and outputs are
-    sliced back to ``d2`` on exit (padded output columns are exactly zero).
+    Ragged buckets are accepted.  Gram mode zero-pads ``d1`` to the next
+    shard multiple (zero rows add nothing to a Gram or a norm and stay zero
+    through the tail).  Subspace mode zero-pads ``d2`` with zero-mask
+    columns threaded through pack/psum/tail, so padded columns contribute
+    exactly zero to every reduction and ``n_eff`` stays the true active
+    count.  Outputs are sliced back on exit; the padding comes out zero.
 
     ``mesh_overlap=True`` chunks the bucket axis B so each chunk's
     collective — the ``(B, d1, r)`` sweep psum and the fused tail's
@@ -1164,10 +1396,8 @@ def robust_pca_bucket_sharded(
     (modules reduce independently), and ``mesh_overlap=False`` runs the
     exact unchunked schedule, so the knob is bit-for-bit off by default.
 
-    The gram svt mode runs the exact projector every iteration, which under
-    sharding means an all-gather of X per iteration — correct but not the
-    scaling path; use ``svt_mode="subspace"`` for collectives that stay
-    constant in the cohort size.
+    The subspace path's exact-eigh fallback still all-gathers X's client
+    columns to form the full Gram; warm-carry rounds never take it.
     """
     shards = mesh_client_shards(mesh)
     if shards == 1:
@@ -1202,15 +1432,25 @@ def robust_pca_bucket_sharded(
                 f"carry basis shape {carry.v.shape} != {(b, d2, r)}; "
                 "was the carry built with a different svt_rank?"
             )
-    from jax.sharding import PartitionSpec as P
-
-    axes = mesh_client_axes(mesh)
-    ax = axes if len(axes) > 1 else axes[0]
     orig_dtype = m.dtype
     m = m.astype(jnp.float32)
     if true_dims is None:
         true_dims = jnp.full((b,), d1p, jnp.int32)
     dims_f = true_dims.astype(jnp.float32)
+    if not use_subspace:
+        l, s, n_done, err, *new_carry = _gram_rows_sharded(
+            m, dims_f, mesh=mesh, n_iter=n_iter, tol=tol, mu=mu, lam=lam,
+            shrink_fn=shrink_fn, fused_tail=fused_tail, interpret=interpret,
+            client_mask=client_mask, r=r, carry=carry,
+            return_carry=return_carry, carry_gate=carry_gate,
+            mesh_overlap=mesh_overlap,
+        )
+        result = RPCAResult(l.astype(orig_dtype), s.astype(orig_dtype), n_done, err)
+        return (result, *new_carry) if return_carry else result
+    from jax.sharding import PartitionSpec as P
+
+    axes = mesh_client_axes(mesh)
+    ax = axes if len(axes) > 1 else axes[0]
     cmask_full = (
         jnp.ones((d2,), jnp.float32)
         if client_mask is None
@@ -1245,12 +1485,6 @@ def robust_pca_bucket_sharded(
         l=col, s=col, y=col, v=P(None, ax, None),
         n_live=rep, n_eff=rep, valid=rep, fall_count=rep, hit=rep,
     )
-
-    def shard_index():
-        idx = jnp.zeros((), jnp.int32)
-        for a in axes:
-            idx = idx * mesh.shape[a] + jax.lax.axis_index(a)
-        return idx
 
     def inner(m_k, dims_f, cmask_k, *rest):
         def gs(x):
@@ -1312,11 +1546,6 @@ def robust_pca_bucket_sharded(
             step_b = -(-b // nch)
             bsl = [(lo, min(lo + step_b, b)) for lo in range(0, b, step_b)]
 
-        def psum_bchunked(part):
-            if len(bsl) == 1:
-                return gs(part)
-            return jnp.concatenate([gs(part[lo:hi]) for lo, hi in bsl], axis=0)
-
         def tail(l, y):
             s = shrink_fn(m_k - l + rho_b * y, thresh[:, None, None]) * cmask_k
             resid = (m_k - l - s) * cmask_k
@@ -1375,7 +1604,7 @@ def robust_pca_bucket_sharded(
             coef = jnp.where(s_ > _EPS, s_shrunk / jnp.maximum(s_, _EPS), 0.0)
             xv = jnp.einsum("bdc,bck->bdk", xg, v_full)
             v_loc = jax.lax.dynamic_slice_in_dim(
-                v_full, shard_index() * d2_loc, d2_loc, axis=1
+                v_full, _shard_index(mesh) * d2_loc, d2_loc, axis=1
             )  # this shard's client rows of the full eigenbasis
             l_k = jnp.einsum("bdk,bk,bck->bdc", xv, coef, v_loc)
             v_top = v_loc[:, :, -r:]
@@ -1516,137 +1745,82 @@ def robust_pca_bucket_sharded(
 
         err0 = jnp.full((b,), jnp.inf, jnp.float32)
         falls0 = jnp.zeros((), jnp.int32)
-
-        if use_subspace:
-            eye_loc = jax.lax.dynamic_slice_in_dim(
-                jnp.broadcast_to(jnp.eye(d2p, r, dtype=jnp.float32), (b, d2p, r)),
-                shard_index() * d2_loc, d2_loc, axis=1,
+        eye_loc = jax.lax.dynamic_slice_in_dim(
+            jnp.broadcast_to(jnp.eye(d2p, r, dtype=jnp.float32), (b, d2p, r)),
+            _shard_index(mesh) * d2_loc, d2_loc, axis=1,
+        )
+        if has_carry:
+            v0 = jnp.where(warm, cin.v, eye_loc)
+            nl0 = jnp.where(warm, cin.n_live, jnp.full((b,), r, jnp.int32))
+            rel0 = jnp.where(
+                warm,
+                jnp.full((b,), 0.5 * svt_fallback_tol, jnp.float32),
+                jnp.full((b,), jnp.inf, jnp.float32),
             )
-            if has_carry:
-                v0 = jnp.where(warm, cin.v, eye_loc)
-                nl0 = jnp.where(warm, cin.n_live, jnp.full((b,), r, jnp.int32))
-                rel0 = jnp.where(
-                    warm,
-                    jnp.full((b,), 0.5 * svt_fallback_tol, jnp.float32),
-                    jnp.full((b,), jnp.inf, jnp.float32),
+        else:
+            v0 = eye_loc
+            nl0 = jnp.full((b,), r, jnp.int32)
+            rel0 = jnp.full((b,), jnp.inf, jnp.float32)
+
+        def step_sub(l, s, y, v_k, n_live, rel, it):
+            x_k = m_k - s + rho_b * y
+            cold = jnp.logical_and(it == 0, jnp.logical_not(warm))
+            if fused_tail:
+                l2, s2, y2, rnorm, v2, live2, rel2, fell = svt_step_fused(
+                    x_k, y, v_k, n_live, rel, cold
                 )
             else:
-                v0 = eye_loc
-                nl0 = jnp.full((b,), r, jnp.int32)
-                rel0 = jnp.full((b,), jnp.inf, jnp.float32)
+                l2, v2, live2, rel2, fell = svt_step(x_k, v_k, n_live, rel, cold)
+                s2, y2, rnorm = tail(l2, y)
+            return l2, s2, y2, rnorm / m_norm, v2, live2, rel2, fell
 
-            def step_sub(l, s, y, v_k, n_live, rel, it):
-                x_k = m_k - s + rho_b * y
-                cold = jnp.logical_and(it == 0, jnp.logical_not(warm))
-                if fused_tail:
-                    l2, s2, y2, rnorm, v2, live2, rel2, fell = svt_step_fused(
-                        x_k, y, v_k, n_live, rel, cold
-                    )
-                else:
-                    l2, v2, live2, rel2, fell = svt_step(x_k, v_k, n_live, rel, cold)
-                    s2, y2, rnorm = tail(l2, y)
-                return l2, s2, y2, rnorm / m_norm, v2, live2, rel2, fell
+        if tol is None:
 
+            def body_sub(it, state):
+                l, s, y, _err, v_k, nl, rl, fc = state
+                l2, s2, y2, err2, v2, nl2, rl2, fell = step_sub(
+                    l, s, y, v_k, nl, rl, it
+                )
+                return (l2, s2, y2, err2, v2, nl2, rl2, fc + fell.astype(jnp.int32))
+
+            l, s, y, err, v_f, nl_f, _, falls = jax.lax.fori_loop(
+                0, n_iter, body_sub, (l0, s0, y0, err0, v0, nl0, rel0, falls0)
+            )
+            n_done = jnp.full((b,), n_iter, jnp.int32)
         else:
 
-            def step_gram(l, s, y):
-                x_k = m_k - s + rho_b * y
-                l2, _, _, _ = exact_svt(x_k, rho)
-                if fused_tail:
-                    s2, y2, rnorm = fused_plain_tail(l2, y)
-                else:
-                    s2, y2, rnorm = tail(l2, y)
-                return l2, s2, y2, rnorm / m_norm
+            def cond_sub(state):
+                return jnp.logical_and(state[4] < n_iter, jnp.any(state[3] > tol))
 
-        falls = falls0
-        if use_subspace:
-            if tol is None:
-
-                def body_sub(it, state):
-                    l, s, y, _err, v_k, nl, rl, fc = state
-                    l2, s2, y2, err2, v2, nl2, rl2, fell = step_sub(
-                        l, s, y, v_k, nl, rl, it
-                    )
-                    return (l2, s2, y2, err2, v2, nl2, rl2, fc + fell.astype(jnp.int32))
-
-                l, s, y, err, v_f, nl_f, _, falls = jax.lax.fori_loop(
-                    0, n_iter, body_sub, (l0, s0, y0, err0, v0, nl0, rel0, falls0)
+            def body_sub(state):
+                l, s, y, err, i, niter, v_k, nl, rl, fc = state
+                l2, s2, y2, err2, v2, nl2, rl2, fell = step_sub(
+                    l, s, y, v_k, nl, rl, i
                 )
-                n_done = jnp.full((b,), n_iter, jnp.int32)
-            else:
-
-                def cond_sub(state):
-                    _, _, _, err, i = state[3], state[3], state[3], state[3], state[4]
-                    return jnp.logical_and(state[4] < n_iter, jnp.any(state[3] > tol))
-
-                def body_sub(state):
-                    l, s, y, err, i, niter, v_k, nl, rl, fc = state
-                    l2, s2, y2, err2, v2, nl2, rl2, fell = step_sub(
-                        l, s, y, v_k, nl, rl, i
-                    )
-                    active = err > tol
-                    sel = lambda new, old: jnp.where(active[:, None, None], new, old)
-                    selv = lambda new, old: jnp.where(active, new, old)
-                    return (
-                        sel(l2, l), sel(s2, s), sel(y2, y), selv(err2, err),
-                        i + 1, jnp.where(active, i + 1, niter),
-                        sel(v2, v_k), selv(nl2, nl), selv(rl2, rl),
-                        fc + fell.astype(jnp.int32),
-                    )
-
-                init = (
-                    l0, s0, y0, err0, jnp.asarray(0, jnp.int32),
-                    jnp.zeros((b,), jnp.int32), v0, nl0, rel0, falls0,
+                active = err > tol
+                sel = lambda new, old: jnp.where(active[:, None, None], new, old)
+                selv = lambda new, old: jnp.where(active, new, old)
+                return (
+                    sel(l2, l), sel(s2, s), sel(y2, y), selv(err2, err),
+                    i + 1, jnp.where(active, i + 1, niter),
+                    sel(v2, v_k), selv(nl2, nl), selv(rl2, rl),
+                    fc + fell.astype(jnp.int32),
                 )
-                l, s, y, err, _, n_done, v_f, nl_f, _, falls = jax.lax.while_loop(
-                    cond_sub, body_sub, init
-                )
-        else:
-            if tol is None:
 
-                def body(_, state):
-                    l, s, y, _err = state
-                    return step_gram(l, s, y)
-
-                l, s, y, err = jax.lax.fori_loop(0, n_iter, body, (l0, s0, y0, err0))
-                n_done = jnp.full((b,), n_iter, jnp.int32)
-            else:
-
-                def cond(state):
-                    return jnp.logical_and(state[4] < n_iter, jnp.any(state[3] > tol))
-
-                def body(state):
-                    l, s, y, err, i, niter = state
-                    l2, s2, y2, err2 = step_gram(l, s, y)
-                    active = err > tol
-                    sel = lambda new, old: jnp.where(active[:, None, None], new, old)
-                    return (
-                        sel(l2, l), sel(s2, s), sel(y2, y),
-                        jnp.where(active, err2, err),
-                        i + 1, jnp.where(active, i + 1, niter),
-                    )
-
-                init = (
-                    l0, s0, y0, err0, jnp.asarray(0, jnp.int32),
-                    jnp.zeros((b,), jnp.int32),
-                )
-                l, s, y, err, _, n_done = jax.lax.while_loop(cond, body, init)
-            v_f = None
-            nl_f = None
+            init = (
+                l0, s0, y0, err0, jnp.asarray(0, jnp.int32),
+                jnp.zeros((b,), jnp.int32), v0, nl0, rel0, falls0,
+            )
+            l, s, y, err, _, n_done, v_f, nl_f, _, falls = jax.lax.while_loop(
+                cond_sub, body_sub, init
+            )
 
         l = l * cmask_k
         outs = (l, s, n_done, err)
         if not return_carry:
             return outs
-        if use_subspace:
-            v_out, nl_out = v_f, nl_f
-        elif has_carry:
-            v_out, nl_out = cin.v, cin.n_live
-        else:
-            v_out = jnp.zeros((b, d2_loc, r), jnp.float32)
-            nl_out = jnp.zeros((b,), jnp.int32)
         new_carry = BucketCarry(
-            l=l, s=s, y=y, v=v_out, n_live=nl_out, n_eff=n_eff_s,
+            l=l, s=s, y=y, v=v_f, n_live=nl_f, n_eff=n_eff_s,
             valid=jnp.ones((), bool), fall_count=falls,
             hit=warm.astype(jnp.float32),
         )

@@ -251,14 +251,17 @@ def padded_cohort(d2: int, shards: int) -> int:
 def bucket_pspec(client_axes: Tuple[str, ...]) -> P:
     """Packed shape-bucket layout ``(modules, padded_vec, cohort)``: client
     columns shard-major over the client mesh axes, everything else
-    replicated — the layout the sharded agg engine's ``shard_map`` loop
-    assumes (DESIGN.md §10).  Ragged cohorts are padded to
+    replicated — where ``pack`` places buckets and the layout the sharded
+    subspace loop assumes (the gram loop moves the bucket to vec rows once
+    on entry, DESIGN.md §10).  Ragged cohorts are padded to
     ``padded_cohort(d2, shards)`` before the spec applies."""
     return P(None, None, client_axes)
 
 
 def bucket_carry_pspecs(client_axes: Tuple[str, ...]):
-    """PartitionSpecs for one ``rpca.BucketCarry`` under client sharding.
+    """PartitionSpecs for one ``rpca.BucketCarry`` under client sharding
+    (the subspace loop's layout; the gram loop holds ``l``/``s``/``y``
+    row-sharded and ``v`` replicated).
 
     The ADMM iterates ``l``/``s``/``y`` shard their client columns exactly
     like the bucket data; the eigenbasis ``v`` ``(B, d2, r)`` shards its

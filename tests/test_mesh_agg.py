@@ -1,20 +1,25 @@
 """Mesh-sharded aggregation (DESIGN.md §10): shard-count invariance.
 
-The contract under test: sharding the packed client axis over host devices
-is a pure execution-layout choice — every method, both SVT modes, masked
-cohorts, RAGGED cohorts (d2 % shards != 0, zero-padded with masked
-columns), the shard-local fused Pallas tail (``rpca_fused_tail``), the
-chunked-psum overlap schedule (``mesh_overlap``), and cross-round carry
-must produce the same numbers at 1, 2, and 4 shards (bitwise at one
-shard, fp32-allclose beyond, where only the collective reduction order
-differs), and the warm-carry path must stay eigh-fallback-free under
-sharding exactly as it is on one device.
+The contract under test: sharding a bucket over host devices is a pure
+execution-layout choice.  The gram SVT shards the vec rows and exchanges
+only (B, C, C) Grams and projectors; the subspace SVT shards the client
+columns.  Every method, both SVT modes, masked cohorts, RAGGED cohorts
+(d2 % shards != 0), the shard-local fused Pallas tail
+(``rpca_fused_tail``), the chunked-psum overlap schedule
+(``mesh_overlap``), and cross-round carry must produce the same numbers at
+1, 2, and 4 shards (bitwise at one shard, fp32-allclose beyond, where only
+the collective reduction order differs), and the warm-carry path must stay
+eigh-fallback-free under sharding exactly as it is on one device.
 
 The multi-device half of the suite needs 4 forced host devices
 (XLA_FLAGS=--xla_force_host_platform_device_count=4 — the CI mesh job
 sets it; conftest.py deliberately never does) and self-skips otherwise,
-so the tier-1 run stays single-device.
+so the tier-1 run stays single-device.  The gram loop's layout test
+compiles on four forced host devices in a subprocess, so it runs in
+tier-1 too.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -123,8 +128,8 @@ class TestSingleDevice:
 
     def test_bucket_carry_pspecs_match_layout(self):
         """partitioning's exported carry specs must match the layout the
-        sharded kernel actually uses: column-sharded l/s/y, row-sharded v,
-        replicated scalars."""
+        sharded subspace loop actually uses: column-sharded l/s/y,
+        row-sharded v, replicated scalars."""
         P = jax.sharding.PartitionSpec
         specs = partitioning.bucket_carry_pspecs(("data",))
         assert isinstance(specs, rpca_lib.BucketCarry)
@@ -291,6 +296,28 @@ class TestShardedCarry:
         for shards in (2, 4):
             outs, falls, _ = self._run(make_host_mesh(shards), trees)
             assert falls == base_falls
+            for a, b in zip(base_outs, outs):
+                assert_trees_close(a, b)
+
+    def test_gram_full_carry_equivalent_across_shard_counts(self, rng):
+        """Gram mode with the full L/S/Y carry: the row-sharded loop takes
+        and returns the carry, on a ragged cohort, as one device does."""
+        trees = round_trees(rng, nc=7, rounds=3)
+
+        def run(mesh):
+            sess = AggSession(session_cfg(svt_mode="gram", carry_mode="full"),
+                              mesh=mesh)
+            outs, hits = [], []
+            for tree in trees:
+                out, diag = sess.step(tree)
+                outs.append(jax.tree_util.tree_map(np.asarray, out))
+                hits.append(float(diag.scalars["carry_hit_rate"]))
+            return outs, hits
+
+        base_outs, base_hits = run(None)
+        for shards in (2, 4):
+            outs, hits = run(make_host_mesh(shards))
+            assert hits == base_hits
             for a, b in zip(base_outs, outs):
                 assert_trees_close(a, b)
 
@@ -471,22 +498,96 @@ class TestShardedFusedTail:
             assert_trees_close(a, b, atol=5e-4, rtol=5e-4)
 
 
+#: A collective in compiled HLO text, whatever its result type (a tuple too).
+COLLECTIVE = re.compile(
+    r"= (.+?) (all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)"
+    r"(-start)?\("
+)
+
+
 @needs4
 @pytest.mark.parametrize("svt_mode", ["gram", "subspace"])
 def test_sharded_collectives_run_under_agg_psum(svt_mode):
     """Every collective of the sharded ADMM loop carries the ``agg.psum``
     scope inside ``agg.admm`` in its HLO ``op_name``: what a device trace's
-    collective time is attributed by."""
-    import re
-
+    collective time is attributed by.  The only cross-shard work outside
+    the loop is the tail's per-module reductions (``agg.tail``)."""
     from repro.launch import steps as steps_lib
 
     tree = round_trees(np.random.default_rng(0), rounds=1)[0]
     cfg = AggregatorConfig(method="fedrpca", rpca_iters=3, svt_mode=svt_mode)
     step = steps_lib.make_agg_step(cfg, mesh=make_host_mesh(4))
     text = jax.jit(step).lower(tree).compile().as_text()
-    collectives = [ln for ln in text.splitlines()
-                   if re.search(r"= \S+ (all-reduce|all-gather)(-start)?\(", ln)]
-    assert collectives
+    collectives = [ln for ln in text.splitlines() if COLLECTIVE.search(ln)]
+    assert any("agg.admm/" in ln for ln in collectives)
     for ln in collectives:
-        assert re.search(r'op_name="[^"]*agg\.admm/[^"]*agg\.psum', ln), ln
+        assert re.search(r'op_name="[^"]*(agg\.admm/[^"]*agg\.psum|agg\.tail/)', ln), ln
+
+
+#: Compiles the gram-mode sharded agg step on four forced host devices and
+#: prints its HLO text: the layout test runs in the tier-1 process, which
+#: has one device.
+GRAM_LAYOUT_RUN = """
+import sys
+import jax
+from repro.core import AggregatorConfig
+from repro.launch import steps
+from repro.launch.mesh import make_host_mesh
+from test_mesh_agg import ragged_layout_tree
+
+cfg = AggregatorConfig(method="fedrpca", rpca_iters=3, svt_mode="gram",
+                       rpca_fused_tail=sys.argv[1] == "fused")
+step = steps.make_agg_step(cfg, mesh=make_host_mesh(4))
+print(jax.jit(step).lower(ragged_layout_tree()).compile().as_text())
+"""
+
+
+def ragged_layout_tree():
+    """7 clients, one bucket of 5 modules at vec 256: neither the cohort
+    nor the module count is a multiple of 4."""
+    rng = np.random.default_rng(0)
+    mk = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)
+    return {"A": mk(7, 4, 16, 16), "head": mk(7, 16, 16)}
+
+
+@pytest.mark.parametrize("tail", ["xla", "fused"])
+def test_gram_loop_exchanges_grams_not_rows(tail):
+    """Compiled on four devices, the gram-mode sharded ADMM loop exchanges
+    only (B, C, C) blocks: no collective inside its ``while`` carries more
+    than ``B_pad * C * C`` floats or the bucket's vec length, and each
+    device's eigh batch is ``B_pad / 4``."""
+    import os
+    import subprocess
+    import sys
+
+    from repro.core.engine import pack
+
+    (b, vec), = pack(ragged_layout_tree())[1].bucket_dims.values()
+    c, shards = 7, 4
+    b_pad = shards * -(-b // shards)
+    assert (b, c) == (5, 7) and vec > c
+    here = os.path.dirname(os.path.abspath(__file__))
+    src = os.path.join(os.path.dirname(here), "src")
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "XLA_FLAGS": "--xla_force_host_platform_device_count=4",
+           "PYTHONPATH": os.pathsep.join([src, here])}
+    p = subprocess.run([sys.executable, "-c", GRAM_LAYOUT_RUN, tail], env=env,
+                       capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stderr[-3000:]
+    loop = [ln for ln in p.stdout.splitlines()
+            if re.search(r'op_name="[^"]*agg\.admm/[^"]*/while/', ln)]
+    dims = lambda ty: [[int(d) for d in s.split(",") if d]
+                       for s in re.findall(r"[a-z]+[0-9]*\[([0-9,]*)\]", ty)]
+    kinds = set()
+    for ln in loop:
+        m = COLLECTIVE.search(ln)
+        if m is None:
+            continue
+        kinds.add(m.group(2))
+        shapes = dims(m.group(1))
+        assert sum(int(np.prod(s)) for s in shapes) <= b_pad * c * c, ln
+        assert not any(vec in s or vec // shards in s for s in shapes), ln
+    assert {"reduce-scatter", "all-gather"} <= kinds
+    eighs = [dims(ln.split(" custom-call(")[0])[0] for ln in loop
+             if " custom-call(" in ln and "/eigh" in ln]
+    assert eighs and all(s == [b_pad // shards, c, c] for s in eighs), eighs

@@ -213,3 +213,24 @@ def test_sharded_aggregation_compiles(topo, monkeypatch):
     text = compile_text(lambda t: aggregate(t, agg, engine="packed", mesh=mesh), stacked)
     assert "tpu_custom_call" in text
     assert "all-reduce" in text
+
+
+@pytest.mark.parametrize("tail", ["xla", "fused"])
+def test_sharded_gram_aggregation_compiles(topo, monkeypatch, tail):
+    """--four-chips' gram comparison: the vec rows sharded 4 ways, the
+    (B, C, C) Grams exchanged, the XLA or the fused tail on each chip."""
+    monkeypatch.setattr(backend, "interpret_default", lambda: False)
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("data", "model"),
+                axis_types=(AxisType.Auto, AxisType.Auto))
+    rep = NamedSharding(mesh, PartitionSpec())
+    cfg = cfglib.get_config(ARCH)
+    lora = jax.eval_shape(lambda k: init_lora_params(k, cfg), jax.random.PRNGKey(0))
+    stacked = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct((CLIENTS,) + x.shape, jnp.float32, sharding=rep),
+        lora,
+    )
+    agg = AggregatorConfig(method="fedrpca", rpca_iters=30, svt_mode="gram",
+                           rpca_fused_tail=tail == "fused")
+    text = compile_text(lambda t: aggregate(t, agg, engine="packed", mesh=mesh), stacked)
+    assert ("tpu_custom_call" in text) == (tail == "fused")
+    assert "all-gather" in text
