@@ -54,7 +54,7 @@ Output contract:
     (``--serve``) {mode: "serve", path: gathered|per_request|merged,
     n_adapters, batch, speedup_vs_per_request, predicted_speedup}, and mesh
     records (``--mesh``) {mode: "mesh", shards, n_clients, round_type,
-    fused, overlap, fallbacks, predicted_us, predicted_peak_bytes,
+    fused, fallbacks, predicted_us, predicted_peak_bytes,
     vs_1shard} — uploaded as a CI artifact so the perf trajectory is
     tracked across PRs.
 """
@@ -527,7 +527,7 @@ MESH_ROUNDS = 3
 
 
 def _mesh_predicted(n_modules: int, cohort: int, shards: int, warm: bool,
-                    fused: bool = False, overlap: bool = False) -> dict:
+                    fused: bool = False) -> dict:
     """Costmodel envelope for one mesh cell, summed over the two canonical
     vec buckets SHAPES populates (64 and 128, half the modules each); the
     per-call dispatch overhead is counted once."""
@@ -540,7 +540,6 @@ def _mesh_predicted(n_modules: int, cohort: int, shards: int, warm: bool,
         mesh_agg_costs(
             n_modules=count, padded_vec=vec, cohort=cohort, shards=shards,
             rpca_iters=MESH_ITERS, warm=warm, fused_tail=fused,
-            overlap=overlap,
         )
         for vec, count in buckets.items() if count
     ]
@@ -555,8 +554,7 @@ def bench_mesh(shards: int, n_clients: int,
                baseline: "tuple[float, float] | None" = None,
                n_modules: int = MESH_MODULES,
                rounds: int = MESH_ROUNDS,
-               fused: bool = False,
-               overlap: bool = False) -> "tuple[float, float] | None":
+               fused: bool = False) -> "tuple[float, float] | None":
     """Mesh-sharded aggregation: client axis split over ``shards`` host
     devices (DESIGN.md §10), cold round vs warm-carry rounds, against the
     ``mesh_agg_costs`` roofline prediction.
@@ -570,10 +568,8 @@ def bench_mesh(shards: int, n_clients: int,
     Returns this cell's (cold_s, warm_s) so the caller can thread it.
 
     ``fused=True`` runs the shard-local Pallas ADMM/sweep tail
-    (``rpca_fused_tail``); ``overlap=True`` adds the chunked-psum
-    comm/compute overlap schedule (``mesh_overlap``).  Both land as
-    booleans in the record so the perf gate can pair each variant with its
-    matching costmodel prediction.
+    (``rpca_fused_tail``); it lands as a boolean in the record so the perf
+    gate can pair each variant with its matching costmodel prediction.
     """
     if shards > jax.device_count():
         common.emit(
@@ -588,7 +584,7 @@ def bench_mesh(shards: int, n_clients: int,
     cfg = AggregatorConfig(
         method="fedrpca", rpca_iters=MESH_ITERS,
         svt_mode="subspace", carry_mode="subspace",
-        rpca_fused_tail=fused, mesh_overlap=overlap,
+        rpca_fused_tail=fused,
     )
     trees = make_round_trees(n_modules, n_clients, rounds, seed=7)
     sess = AggSession(cfg, mesh=mesh)
@@ -608,16 +604,14 @@ def bench_mesh(shards: int, n_clients: int,
         warm_times.append(time.perf_counter() - t0)
         warm_falls.append(int(diag.scalars["fallback_count"]))
     warm_s = min(warm_times)
-    tag = (f"s{shards}_c{n_clients}"
-           + ("_fused" if fused else "") + ("_ovl" if overlap else ""))
+    tag = f"s{shards}_c{n_clients}" + ("_fused" if fused else "")
     for round_type, s, falls, base in (
         ("cold", cold_s, int(cold_diag.scalars["fallback_count"]),
          baseline[0] if baseline else None),
         ("warm", warm_s, max(warm_falls), baseline[1] if baseline else None),
     ):
         pred = _mesh_predicted(n_modules, n_clients, shards,
-                               round_type == "warm", fused=fused,
-                               overlap=overlap)
+                               round_type == "warm", fused=fused)
         extra = f" vs_1shard={base / s:.2f}x" if base else ""
         record(
             f"agg_mesh_{round_type}_{tag}", s * 1e6,
@@ -625,7 +619,7 @@ def bench_mesh(shards: int, n_clients: int,
             f"fallbacks={falls} compile={compile_s:.2f}s{extra}",
             mode="mesh", shards=shards, n_clients=n_clients,
             n_modules=n_modules, round_type=round_type, rounds=rounds,
-            fused=fused, overlap=overlap,
+            fused=fused,
             fallbacks=falls, predicted_us=round(pred["us"], 1),
             predicted_peak_bytes=int(pred["peak_bytes_per_shard"]),
             predicted_comm_fraction=round(pred["comm_fraction"], 3),
@@ -831,15 +825,12 @@ def main(quick: bool | None = None, rounds: int = 0, carry_mode: str = "subspace
                 if shards == 1:
                     base = got
             # Sharded fused-tail variants (DESIGN.md §10): the shard-local
-            # Pallas tail alone, then with the chunked-psum overlap
-            # schedule, at every multi-device shard count — both against
-            # the same 1-shard baseline so vs_1shard compares schedules.
+            # Pallas tail at every multi-device shard count, against the
+            # same 1-shard baseline so vs_1shard compares schedules.
             for shards in MESH_SHARDS:
                 if shards == 1:
                     continue
                 bench_mesh(shards, n_clients, baseline=base, fused=True)
-                bench_mesh(shards, n_clients, baseline=base, fused=True,
-                           overlap=True)
     if faults:
         bench_faults(rounds or 10, n_clients=8 if quick else 16)
     if uplink:

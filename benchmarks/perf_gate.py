@@ -19,12 +19,12 @@ costmodel-derived bounds each engine PR established:
     largest adapters x batch cell, and its win must grow with batch at
     fixed adapter count (the crossover the pool layout exists for).
   * mesh: every mode="mesh" cell's measured wall time must sit inside the
-    ``costmodel.mesh_agg_costs`` envelope band — fused / overlap variants
-    against their matching costmodel prediction — warm mesh rounds must
-    also be fallback-free (fused ones included: the sharded Pallas tail
-    must not reintroduce eigh fallbacks), and wherever a cohort has a
-    1-shard cell the 4-shard warm cell plus its fused and fused+overlap
-    variants must be present and in-envelope (the scale-out acceptance
+    ``costmodel.mesh_agg_costs`` envelope band — fused variants against
+    their matching costmodel prediction — warm mesh rounds must also be
+    fallback-free (fused ones included: the sharded Pallas tail must not
+    reintroduce eigh fallbacks), and wherever a cohort has a 1-shard cell
+    the 4-shard warm cell and its fused variant must be present and
+    in-envelope (the scale-out acceptance
     cells: sharding keeps working where one device is at its
     memory-footprint worst).
   * faults: every mode="faults" run must end with a finite state, and at
@@ -225,8 +225,7 @@ def gate_mesh(records: list[dict]) -> None:
     lo, hi = MESH_ENVELOPE
 
     def variant(r: dict) -> str:
-        return (("_fused" if r.get("fused") else "")
-                + ("_ovl" if r.get("overlap") else ""))
+        return "_fused" if r.get("fused") else ""
 
     for r in cells:
         env = r["us_per_call"] / r["predicted_us"]
@@ -244,25 +243,22 @@ def gate_mesh(records: list[dict]) -> None:
                 "(must be 0)",
             )
     # Scale-out acceptance: wherever a cohort ran at 1 shard, the 4-shard
-    # warm cell AND its fused / fused+overlap variants must exist and be
+    # warm cell AND its fused variant must exist and be
     # in-envelope (checked above) — here we just require their presence so
     # a silently-skipped cell (too few devices) cannot pass the gate.
     cohorts = {r["n_clients"] for r in cells if r["shards"] == 1}
     for c in sorted(cohorts):
-        for fused, overlap, label in (
-            (False, False, ""), (True, False, "_fused"), (True, True, "_fused_ovl"),
-        ):
+        for fused, label in ((False, ""), (True, "_fused")):
             has4 = any(
                 r["shards"] == 4 and r["n_clients"] == c
                 and r["round_type"] == "warm"
                 and bool(r.get("fused")) == fused
-                and bool(r.get("overlap")) == overlap
                 for r in cells
             )
             check(has4, f"mesh_4shard_present_c{c}{label}",
                   "4-shard warm cell recorded" if has4
                   else "4-shard warm cell missing (skipped? too few host "
-                       "devices, or the fused/overlap variants did not run)")
+                       "devices, or the fused variant did not run)")
 
 
 def gate_faults(records: list[dict]) -> None:

@@ -14,17 +14,18 @@ One chip (no arguments):
      sparse deltas of the same tree.
 
 ``--four-chips``: training with the aggregation sharded over four chips
-(``--mesh-shards 4 --rpca-fused-tail``, 2 rounds), then the sharded fused
-subspace aggregation (real deltas) and decomposition (both inputs), and the
-sharded gram ones with the XLA tail, against the single-device ones on
-chip 0.
+(``--mesh-shards 4 --rpca-fused-tail``, 2 rounds), then, through
+``robust_pca_bucket(mesh=...)`` with the bucket's vec rows split four ways,
+the sharded fused subspace aggregation (real deltas) and decomposition
+(both inputs), and the sharded gram ones with the XLA tail, against the
+single-device ones on chip 0.
 
 Real deltas have live rank 8 = the cohort, so every ADMM iteration takes the
 exact-eigh SVT.  The planted deltas (rank 2 + 1% spikes) let the subspace
 SVT take its Ritz path after some exact iterations; the smoke requires that
-it did (exact-eigh count below the iteration count), so the Ritz-path fused
-kernels (``subspace_apply`` with a Ritz projector, sharded
-``subspace_apply_factored``) run too.
+it did (exact-eigh count below the iteration count), so the fused kernel
+``subspace_apply`` runs with a Ritz projector too, on one chip and on each
+of four.
 
 Fails (exit 1, no JSON line) on a non-finite loss, a retried or degraded
 round, a fused kernel that did not compile to a TPU custom call, a Ritz path
@@ -262,13 +263,12 @@ def bucket_decomposition(svt_mode: str, fused: bool, mesh=None):
     """deltas -> ([(L, S)] of their packed buckets, [exact-eigh iterations])."""
     from repro.core import engine, rpca
 
-    kw = dict(n_iter=RPCA_ITERS, svt_mode=svt_mode, fused_tail=fused, return_carry=True)
-    if mesh is not None:
-        kw["mesh"] = mesh
-    run = rpca.robust_pca_bucket if mesh is None else rpca.robust_pca_bucket_sharded
+    kw = dict(mesh=mesh, n_iter=RPCA_ITERS, svt_mode=svt_mode, fused_tail=fused,
+              return_carry=True)
 
     def fn(deltas):
-        results = [run(b.data, b.true_dims, **kw) for b in engine.pack(deltas)[0].values()]
+        results = [rpca.robust_pca_bucket(b.data, b.true_dims, **kw)
+                   for b in engine.pack(deltas)[0].values()]
         return ([(r.low_rank, r.sparse) for r, _ in results],
                 [carry.fall_count for _, carry in results])
 
@@ -311,7 +311,7 @@ def sharded_parity(smoke: Smoke, inputs: dict) -> None:
     runs("one-chip", aggregation(cfg, engine="packed"), kernel=True, only="real")
     hlo = runs("sharded", aggregation(cfg, engine="packed", mesh=mesh), kernel=True,
                only="real")
-    smoke.check("all-reduce" in hlo, "the sharded aggregation has no all-reduce")
+    smoke.check("all-gather" in hlo, "the sharded aggregation has no all-gather")
     runs("one-chip-LS", bucket_decomposition("subspace", True), kernel=True, unit=True)
     runs("sharded-LS", bucket_decomposition("subspace", True, mesh), kernel=True,
          unit=True)
@@ -323,7 +323,7 @@ def sharded_parity(smoke: Smoke, inputs: dict) -> None:
     runs.compare("sharded-LS", "one-chip-LS", TOL_SHARDED_DECOMP)
     runs.took_ritz_path("one-chip-LS")
     runs.took_ritz_path("sharded-LS")
-    # The gram loop shards the vec rows and exchanges (B, C, C) Grams.
+    # Both modes shard the vec rows and exchange (B, C, C) Grams.
     gram = cfg.replace(svt_mode="gram", rpca_fused_tail=False)
     runs("gram/one-chip", aggregation(gram, engine="packed"), only="real")
     hlo = runs("gram/sharded", aggregation(gram, engine="packed", mesh=mesh), only="real")

@@ -71,7 +71,6 @@ class AggregatorConfig:
     rpca_tol: float = 1e-7  # stopping tolerance when rpca_fixed_iters=False
     rpca_fixed_iters: bool = True  # False: tolerance-based early stopping
     rpca_fused_tail: bool = False  # packed engine: Pallas fused ADMM tail
-    mesh_overlap: bool = False  # sharded agg: B-chunk psums to overlap comm/compute
     svt_mode: str = "gram"  # gram (per-iteration eigh) | subspace (warm-started)
     svt_rank: int = 8  # subspace mode: carried basis width cap
     svt_sweeps: int = 2  # subspace mode: power sweeps per ADMM iteration

@@ -138,8 +138,10 @@ def pack(
     client axis onto the mesh's client axes (shard-major column placement:
     contiguous column blocks per shard, so tier gathers, ``migrate_carry``
     and ``plan_retier`` stay shard-local) and the mask/weight vectors along
-    the same axis.  One-shard meshes are a no-op — callers normalize them
-    to None via ``plan_aggregation``.
+    the same axis.  This is the layout of every method but fedrpca, whose
+    RPCA loop moves each bucket to vec rows once on entry
+    (``rpca.bucket_pspecs``, DESIGN.md §10).  One-shard meshes are a no-op
+    — callers normalize them to None via ``plan_aggregation``.
     """
     if granularity not in ("module", "leaf"):
         raise ValueError(f"unknown granularity: {granularity!r}")
@@ -256,8 +258,8 @@ def pack(
         def constrain(x, spec, client_dim):
             # Placement hint only.  Eager with_sharding_constraint routes
             # through jit out_shardings, which rejects unevenly divisible
-            # dims — ragged cohorts skip the hint and let the sharded
-            # loop's internal zero-pad own the column layout.
+            # dims — ragged cohorts skip the hint and let GSPMD place
+            # the columns.
             if x.shape[client_dim] % n_shards:
                 return x
             return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
@@ -448,10 +450,10 @@ def _fedrpca_bucket(
     stateless call, in which case the returned carry is None too);
     ``svt_rank`` overrides the config's basis-width cap — the two-tier
     re-pack runs converged tiers at a tighter cap.  ``mesh`` (multi-shard)
-    routes the ADMM loop through ``robust_pca_bucket_sharded``; the
-    column-mean tail stays a plain einsum, which GSPMD partitions along the
-    loop's output layout (client columns in subspace mode, vec rows in gram
-    mode, where the client means are shard-local).
+    runs the ADMM loop on the bucket's vec rows split over the mesh
+    (``robust_pca_bucket(mesh=...)``); the column-mean tail stays a plain
+    einsum, which GSPMD partitions along the loop's row layout, where the
+    client means are shard-local.
 
     ``uplink`` (an active ``fed.sketch.UplinkConfig``, carry required)
     replaces the dense client columns with their sketch round-trip —
@@ -502,15 +504,11 @@ def _fedrpca_bucket(
         }
     if col_scaled:
         m = m * (bucket.weights * n_eff)[None, None, :]
-    rpca_fn = rpca_lib.robust_pca_bucket
-    rpca_kwargs = {}
     sharded = mesh is not None and rpca_lib.mesh_client_shards(mesh) > 1
-    if sharded:
-        rpca_fn = rpca_lib.robust_pca_bucket_sharded
-        rpca_kwargs = {"mesh": mesh, "mesh_overlap": cfg.mesh_overlap}
-    res = rpca_fn(
+    res = rpca_lib.robust_pca_bucket(
         m,
         bucket.true_dims,
+        mesh=mesh,
         n_iter=cfg.rpca_iters,
         tol=None if cfg.rpca_fixed_iters else cfg.rpca_tol,
         shrink_fn=shrink_fn,
@@ -524,7 +522,6 @@ def _fedrpca_bucket(
         return_carry=carry is not None,
         carry_gate=cfg.carry_gate,
         true_cols=true_cols,
-        **rpca_kwargs,
     )
     new_carry = None
     if carry is not None:
@@ -563,7 +560,7 @@ def _fedrpca_bucket(
             beta = jnp.full(energy.shape, cfg.beta, jnp.float32)
         update = low_mean + beta[:, None] * sparse_mean
         if sharded:
-            # The gram loop leaves L and S row-sharded: replicate the
+            # The loop leaves L and S row-sharded: replicate the
             # (B, vec) update here, once, and not leaf by leaf in unpack.
             from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -803,12 +800,10 @@ def plan_aggregation(
 
     ``mesh`` requests client-axis sharding.  Plans normalize one-shard
     meshes (the ``(1, 1)`` debug mesh included) to ``mesh=None`` so the
-    single-device trace stays bitwise identical.  What used to be plan-time
-    refusals are now capabilities of the sharded loop: ragged cohorts
-    (``cohort_size % shards != 0``) are zero-padded with masked columns
-    inside ``robust_pca_bucket_sharded``, and ``rpca_fused_tail`` runs the
-    Pallas tail kernels shard-locally on each shard's column slice
-    (DESIGN.md §10).
+    single-device trace stays bitwise identical.  The sharded loop splits
+    vec rows, so ragged cohorts (``cohort_size % shards != 0``) shard as
+    they are, and ``rpca_fused_tail`` runs the Pallas tail kernels
+    shard-locally on each shard's rows (DESIGN.md §10).
 
     ``uplink`` is the compressed-uplink codec config (a
     ``fed.sketch.UplinkConfig``, or a spec string for

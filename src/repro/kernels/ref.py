@@ -71,38 +71,6 @@ def svt_subspace_apply_ref(
     return low, s_new, y_new, rsq, g_next
 
 
-def svt_subspace_apply_factored_ref(
-    m: jnp.ndarray,  # (B, vec, clients)
-    y: jnp.ndarray,
-    f: jnp.ndarray,  # (B, vec, r) replicated shrink factor (X Vr) coef
-    vr: jnp.ndarray,  # (B, clients, r) shard-local Ritz basis rows
-    rho: jnp.ndarray,  # (B,) per-module scalars
-    mu: jnp.ndarray,
-    thresh: jnp.ndarray,
-    mask=None,  # optional (clients,) validity mask
-) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Fused factored-projector SVT tail: ``L = F Vr^T`` then shrink, dual
-    ascent, and the per-module residual sumsq *partial* for these columns.
-
-    The mesh-sharded twin of ``svt_subspace_apply_ref``: the d2 x d2
-    projector is replaced by its rank-r factorization, so the oracle (like
-    the kernel) only ever sees one shard's column slice.  No Gram rides
-    along — the sharded loop rebuilds sweep reductions from X directly.
-    """
-    rho_ = rho[:, None, None].astype(m.dtype)
-    mu_ = mu[:, None, None].astype(m.dtype)
-    th_ = thresh[:, None, None].astype(m.dtype)
-    msk = 1.0 if mask is None else jnp.asarray(mask, m.dtype)[None, None, :]
-    low = jnp.einsum(
-        "bdr,bcr->bdc", f.astype(jnp.float32), vr.astype(jnp.float32)
-    ).astype(m.dtype)
-    s_new = soft_threshold_ref(m - low + rho_ * y, th_) * msk
-    resid = (m - low - s_new) * msk
-    y_new = (y + mu_ * resid) * msk
-    rsq = jnp.sum(jnp.square(resid.astype(jnp.float32)), axis=(1, 2))
-    return low, s_new, y_new, rsq
-
-
 def lora_matmul_ref(
     x: jnp.ndarray, w: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray, scale: float
 ) -> jnp.ndarray:

@@ -34,17 +34,9 @@ the Gram accumulator never sees them.  L is deliberately *not* masked here
 (parity with the jnp path; ``robust_pca_bucket`` applies the single final
 mask pass).  The jnp oracle is ``kernels/ref.py::svt_subspace_apply_ref``.
 
-Under client-axis sharding (DESIGN.md §10) the full (d2, d2) projector is
-never materialized — the Ritz SVT yields a *replicated* thin factor
-``F = (X Vr) diag(shrink(s)/s)`` of shape (B, d1, r) plus this shard's
-basis rows ``Vr_k`` of shape (B, d2_loc, r), and ``L_k = F Vr_k^T``.
-``subspace_apply_factored`` fuses that rank-r reconstruction with the
-elementwise tail in one VMEM pass per shard: each kernel instance is
-single-device, the mask is the shard's column slice of the cohort mask
-(ragged cohorts pad with zero-mask columns), and the per-shard residual
-partial sums are psum-reduced by the caller.  No Gram accumulator rides
-along — the sharded loop rebuilds sweep reductions from X directly.  The
-jnp oracle is ``kernels/ref.py::svt_subspace_apply_factored_ref``.
+On a mesh (DESIGN.md §10) the kernel runs shard-locally on the shard's vec
+rows of every client, with the full projector: its residual sums and Gram
+are the shard's partials, which the loop reduces across shards.
 """
 from __future__ import annotations
 
@@ -169,118 +161,3 @@ def subspace_apply(
     if pad_v:
         l, s_new, y_new = l[:, :d1, :], s_new[:, :d1, :], y_new[:, :d1, :]
     return l, s_new, y_new, rsq, g_next
-
-
-def _kernel_factored(
-    rho_ref, mu_ref, th_ref, mask_ref, vr_ref, m_ref, y_ref, f_ref,
-    l_ref, so_ref, yo_ref, r_ref,
-):
-    i, j = pl.program_id(0), pl.program_id(1)
-    rho = rho_ref[i]
-    mu = mu_ref[i]
-    th = th_ref[i]
-    msk = mask_ref[0]  # (1, d2) client validity; all-ones when dense
-    vr = vr_ref[0]  # (d2, r) this shard's Ritz basis rows
-    m = m_ref[0]  # (block_vec, d2)
-    y = y_ref[0]
-    f = f_ref[0]  # (block_vec, r) replicated shrink factor (X Vr) coef
-    l = jnp.dot(f, vr.T, preferred_element_type=jnp.float32).astype(m.dtype)
-    z = m - l + rho * y
-    s_new = (jnp.sign(z) * jnp.maximum(jnp.abs(z) - th, 0.0)) * msk
-    resid = (m - l - s_new) * msk
-    y_new = (y + mu * resid) * msk
-    l_ref[0] = l
-    so_ref[0] = s_new
-    yo_ref[0] = y_new
-    part = jnp.sum(jnp.square(resid.astype(jnp.float32)))
-
-    @pl.when(j == 0)
-    def _init():
-        r_ref[i] = part
-
-    @pl.when(j > 0)
-    def _acc():
-        r_ref[i] += part
-
-
-@functools.partial(jax.jit, static_argnames=("block_vec", "interpret"))
-def subspace_apply_factored(
-    m: jnp.ndarray,
-    y: jnp.ndarray,
-    f: jnp.ndarray,
-    vr: jnp.ndarray,
-    rho: jnp.ndarray,
-    mu: jnp.ndarray,
-    thresh: jnp.ndarray,
-    *,
-    mask: Optional[jnp.ndarray] = None,
-    block_vec: int = DEFAULT_BLOCK_VEC,
-    interpret: Optional[bool] = None,
-) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Fused factored-projector SVT tail: ``L = F Vr^T`` + elementwise tail.
-
-    The shard-local twin of ``subspace_apply`` for the mesh path: instead of
-    a (B, d2, d2) projector it takes the rank-r factorization the sharded
-    Ritz SVT already has in hand — the replicated shrink factor ``F = (X Vr)
-    diag(shrink(s)/s)`` and this shard's basis rows ``Vr`` — so each shard
-    reconstructs only its own L columns and no d2^2 object ever exists.
-
-    Args:
-      m, y: (B, vec_dim, d2) current iterate slices (d2 = this shard's
-        column count under sharding, the full cohort on one device).
-      f: (B, vec_dim, r) replicated factor ``(X Vr) diag(shrink(s)/s)``.
-      vr: (B, d2, r) Ritz basis rows for these columns.
-      rho, mu, thresh: per-module (B,) ADMM scalars; ``thresh = rho * lam``.
-      mask: optional (d2,) column validity mask (shard slice of the cohort
-        mask; zero for ragged padding columns).  Masked columns of S'/Y' are
-        forced to exactly zero and excluded from the residual sums.
-      block_vec: tile size along the vec dimension.
-      interpret: Pallas interpret mode; None autodetects per platform.
-
-    Returns:
-      (L, S', Y', resid_sumsq) with resid_sumsq a (B,) float32 array of
-      *this shard's partial* ``sum((M - L - S')^2)`` — the caller psums it
-      across shards before the convergence check.
-    """
-    interpret = backend.resolve_interpret(interpret)
-    if m.ndim != 3:
-        raise ValueError(f"expected (B, vec, clients) input, got {m.shape}")
-    if m.shape != y.shape:
-        raise ValueError(f"shape mismatch: {m.shape} {y.shape}")
-    b, d1, d2 = m.shape
-    r = f.shape[-1]
-    if f.shape != (b, d1, r):
-        raise ValueError(f"factor shape {f.shape} != {(b, d1, r)}")
-    if vr.shape != (b, d2, r):
-        raise ValueError(f"basis shape {vr.shape} != {(b, d2, r)}")
-    bv = min(block_vec, max(d1, 1))
-    pad_v = (-d1) % bv
-    if pad_v:
-        padder = lambda t: jnp.pad(t, ((0, 0), (0, pad_v), (0, 0)))
-        m, y, f = padder(m), padder(y), padder(f)
-    grid = (b, m.shape[1] // bv)
-    scal = lambda v: jnp.asarray(v, jnp.float32).reshape(b)
-    mvec = jnp.ones((d2,), jnp.float32) if mask is None else jnp.asarray(mask, jnp.float32)
-    mvec = mvec.reshape(1, 1, d2)
-    sspec = pl.BlockSpec(memory_space=pltpu.SMEM)  # whole (B,) array
-    mspec = pl.BlockSpec((1, 1, d2), lambda i, j: (0, 0, 0))
-    vspec = pl.BlockSpec((1, d2, r), lambda i, j: (i, 0, 0))
-    tspec = pl.BlockSpec((1, bv, d2), lambda i, j: (i, j, 0))
-    fspec = pl.BlockSpec((1, bv, r), lambda i, j: (i, j, 0))
-    l, s_new, y_new, rsq = pl.pallas_call(
-        _kernel_factored,
-        grid=grid,
-        in_specs=[sspec, sspec, sspec, mspec, vspec, tspec, tspec, fspec],
-        out_specs=[tspec, tspec, tspec, sspec],
-        out_shape=[
-            jax.ShapeDtypeStruct(m.shape, m.dtype),
-            jax.ShapeDtypeStruct(m.shape, m.dtype),
-            jax.ShapeDtypeStruct(m.shape, m.dtype),
-            jax.ShapeDtypeStruct((b,), jnp.float32),
-        ],
-        interpret=interpret,
-    )(scal(rho), scal(mu), scal(thresh), mvec, vr.astype(jnp.float32),
-      m, y, f.astype(m.dtype))
-    if pad_v:
-        l, s_new, y_new = l[:, :d1, :], s_new[:, :d1, :], y_new[:, :d1, :]
-    return l, s_new, y_new, rsq

@@ -504,7 +504,6 @@ def mesh_agg_costs(
     dtype_bytes: int = 4,
     shared_host_core: bool = True,
     fused_tail: bool = False,
-    overlap: bool = False,
 ) -> Dict[str, float]:
     """Analytic round cost of one mesh-sharded RPCA bucket (DESIGN.md §10).
 
@@ -531,11 +530,6 @@ def mesh_agg_costs(
     pass over the (B, d1, c_loc) slice instead of ~5 separate HBM
     round-trips, cutting the tail's HBM traffic to one read+write of the
     operand set.  FLOPs are unchanged (same math, fewer materialisations).
-
-    ``overlap=True`` models the chunked-psum schedule (``mesh_overlap``):
-    the bucket axis is split so chunk k+1's sweep all-reduce issues while
-    chunk k's tail executes, hiding the smaller of compute/comm time:
-    ``us = max(compute, comm) + dispatch`` instead of their sum.
 
     ``shared_host_core=True`` (the CI/container reality) divides the
     per-shard FLOP peak by the shard count — host-platform devices are
@@ -606,13 +600,7 @@ def mesh_agg_costs(
         (allreduce_bytes + gather_bytes) / MESH_BW_COLL
         + n_collectives * MESH_COLL_OVERHEAD_US
     )
-    if overlap:
-        # Chunked-psum schedule: chunk k+1's all-reduce overlaps chunk k's
-        # tail, so the shorter leg hides behind the longer one.  Dispatch
-        # stays serial (it gates the first chunk).
-        us = max(compute_us, comm_us) + MESH_DISPATCH_US
-    else:
-        us = compute_us + comm_us + MESH_DISPATCH_US
+    us = compute_us + comm_us + MESH_DISPATCH_US
     return {
         "local_flops": local_flops,
         "local_hbm_bytes": local_bytes,

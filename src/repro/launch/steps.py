@@ -242,10 +242,9 @@ def make_agg_step(
     ``mesh`` shards the packed client axis of the aggregation across the
     mesh's client axes (packed engine only — DESIGN.md §10); one-shard
     meshes are normalized away, keeping the single-device trace bitwise.
-    Ragged cohorts (clients not divisible by the shard count) are padded
-    with masked zero columns inside the sharded loop, and
-    ``agg_cfg.rpca_fused_tail`` / ``agg_cfg.mesh_overlap`` select the
-    shard-local fused Pallas tail and the chunked-psum overlap schedule.
+    The RPCA loop splits each bucket's vec rows over those axes, so ragged
+    cohorts (clients not divisible by the shard count) need no padding,
+    and ``agg_cfg.rpca_fused_tail`` runs the Pallas tail shard-locally.
 
     ``uplink`` selects the client->server wire codec (DESIGN.md §12) —
     None/"dense" is the exact legacy wire; "sketch[:k[:tol]]" (or an

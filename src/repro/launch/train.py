@@ -165,12 +165,7 @@ def main(argv=None):
                     help="route the RPCA elementwise tail through the fused "
                          "Pallas kernels (packed engine; under --mesh-shards "
                          "the kernels run shard-locally on each shard's "
-                         "column slice — DESIGN.md §10)")
-    ap.add_argument("--mesh-overlap", action="store_true",
-                    help="sharded aggregation: chunk the bucket axis so each "
-                         "chunk's sweep/tail all-reduce overlaps the next "
-                         "chunk's compute (no-op without --mesh-shards > 1; "
-                         "off reproduces the unchunked schedule bit-for-bit)")
+                         "vec rows — DESIGN.md §10)")
     ap.add_argument("--svt-mode", default="gram", choices=list(SVT_MODES),
                     help="RPCA SVT step: per-iteration eigh (gram) or "
                          "warm-started subspace iteration (subspace)")
@@ -332,7 +327,7 @@ def main(argv=None):
         method=args.aggregator, rpca_iters=args.rpca_iters, weighting=args.weighting,
         svt_mode=args.svt_mode, svt_rank=args.svt_rank, svt_sweeps=args.svt_sweeps,
         carry_mode=args.carry_mode,
-        rpca_fused_tail=args.rpca_fused_tail, mesh_overlap=args.mesh_overlap,
+        rpca_fused_tail=args.rpca_fused_tail,
         guard_energy_k=guard_cfg.energy_k if guard_cfg is not None else 0.0,
     )
     # Cross-round aggregation session: the carry pytree is initialized once

@@ -236,47 +236,9 @@ def stacked_lora_pspecs(lora: PyTree, client_axes: Tuple[str, ...]) -> PyTree:
 
 
 def padded_cohort(d2: int, shards: int) -> int:
-    """Smallest multiple of ``shards`` >= ``d2``.
-
-    Ragged cohorts (``d2 % shards != 0``) shard by zero-padding the client
-    axis to this size with zero-mask columns before ``shard_map`` — padded
-    columns carry a zero validity mask through every psum/tail, so they
-    contribute nothing and ``n_eff`` stays the true count (DESIGN.md §10).
-    """
+    """Smallest multiple of ``shards`` >= ``d2``: the client-axis length a
+    ragged cohort (``d2 % shards != 0``) takes when its columns are placed
+    shard-major with zero-mask padding."""
     if shards <= 0:
         raise ValueError(f"shards must be positive, got {shards}")
     return shards * (-(-d2 // shards))
-
-
-def bucket_pspec(client_axes: Tuple[str, ...]) -> P:
-    """Packed shape-bucket layout ``(modules, padded_vec, cohort)``: client
-    columns shard-major over the client mesh axes, everything else
-    replicated — where ``pack`` places buckets and the layout the sharded
-    subspace loop assumes (the gram loop moves the bucket to vec rows once
-    on entry, DESIGN.md §10).  Ragged cohorts are padded to
-    ``padded_cohort(d2, shards)`` before the spec applies."""
-    return P(None, None, client_axes)
-
-
-def bucket_carry_pspecs(client_axes: Tuple[str, ...]):
-    """PartitionSpecs for one ``rpca.BucketCarry`` under client sharding
-    (the subspace loop's layout; the gram loop holds ``l``/``s``/``y``
-    row-sharded and ``v`` replicated).
-
-    The ADMM iterates ``l``/``s``/``y`` shard their client columns exactly
-    like the bucket data; the eigenbasis ``v`` ``(B, d2, r)`` shards its
-    *rows* (one row per client) along the same axes, so ``x_k @ v_k``
-    partial products psum into the replicated projected factor; the
-    live-rank / fingerprint / health scalars are replicated.  Returned as a
-    ``BucketCarry`` of specs so it maps 1:1 onto the carry pytree (usable
-    directly as ``shard_map`` in/out specs).
-    """
-    from repro.core import rpca as rpca_lib
-
-    col = bucket_pspec(client_axes)
-    rep = P()
-    return rpca_lib.BucketCarry(
-        l=col, s=col, y=col,
-        v=P(None, client_axes, None),
-        n_live=rep, n_eff=rep, valid=rep, fall_count=rep, hit=rep,
-    )

@@ -13,7 +13,6 @@ from repro.core import (
     robust_pca_fixed_iters,
     soft_threshold,
     svt_gram,
-    svt_gram_batched,
     svt_svd,
 )
 
@@ -65,17 +64,20 @@ class TestSVT:
 
     @pytest.mark.parametrize("dims,vec,cols", [([64, 40, 17], 64, 6), ([5, 3, 1], 5, 8)])
     def test_batched_padded_bucket(self, dims, vec, cols, rng):
-        """Zero-padded rows stay exactly zero and the true rows match the
-        unpadded call, in the tall bucket and in the wide (transposed) one."""
+        """The bucket loop's SVT stage (its first iteration's L, the SVT of
+        M at threshold 1/mu): zero-padded rows stay exactly zero and the
+        true rows match the unpadded per-matrix call, in the tall bucket
+        and in the wide one (whose per-matrix call transposes)."""
         x = np.zeros((len(dims), vec, cols), np.float32)
         for i, d in enumerate(dims):
             x[i, :d] = rng.normal(size=(d, cols))
-        t = jnp.asarray([0.3, 1.0, 2.0], jnp.float32)
-        out = np.asarray(svt_gram_batched(jnp.asarray(x), t))
-        for i, d in enumerate(dims):
-            assert not np.any(out[i, d:])
-            np.testing.assert_allclose(
-                out[i, :d], svt_gram(jnp.asarray(x[i, :d]), t[i]), atol=1e-5, rtol=1e-5)
+        for t in (0.3, 1.0, 2.0):
+            out = np.asarray(robust_pca_bucket(
+                jnp.asarray(x), jnp.asarray(dims), n_iter=1, mu=1.0 / t).low_rank)
+            for i, d in enumerate(dims):
+                assert not np.any(out[i, d:])
+                np.testing.assert_allclose(
+                    out[i, :d], svt_gram(jnp.asarray(x[i, :d]), t), atol=1e-5, rtol=1e-5)
 
     @pytest.mark.parametrize("shape", [(64, 6), (6, 64)])
     def test_gram_streams_x_twice(self, shape):

@@ -295,60 +295,6 @@ class TestFusedSweepTail:
         np.testing.assert_allclose(fused.sparse, plain.sparse, atol=2e-5)
 
 
-class TestFactoredSweepTail:
-    """kernels/svt_subspace.subspace_apply_factored vs the jnp oracle.
-
-    The sharded fused path's kernel: L = F Vr^T from the rank-r Ritz
-    factorization (F replicated, Vr shard-local rows) fused with the
-    shrink / residual / dual tail — no d2 x d2 projector ever forms."""
-
-    def _inputs(self, rng, b, d, nc, r):
-        m, y = (jnp.asarray(rng.normal(size=(b, d, nc)), jnp.float32)
-                for _ in range(2))
-        f = jnp.asarray(rng.normal(size=(b, d, r)), jnp.float32)
-        vr = jnp.asarray(rng.normal(size=(b, nc, r)), jnp.float32)
-        rho = jnp.asarray(rng.uniform(0.5, 2.0, b), jnp.float32)
-        return m, y, f, vr, rho, 1.0 / rho, rho * 0.1
-
-    @pytest.mark.parametrize("b,d,nc,r", [(3, 64, 8, 4), (2, 100, 12, 3),
-                                          (1, 1, 1, 1)])
-    @pytest.mark.parametrize("block_vec", [32, 512])
-    def test_factored_apply(self, b, d, nc, r, block_vec, rng):
-        m, y, f, vr, rho, mu, th = self._inputs(rng, b, d, nc, r)
-        got = svt_kernel.subspace_apply_factored(
-            m, y, f, vr, rho, mu, th, block_vec=block_vec, interpret=True
-        )
-        want = ref.svt_subspace_apply_factored_ref(m, y, f, vr, rho, mu, th)
-        for g, w, name in zip(got, want, ("L", "S", "Y", "rsq")):
-            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
-                                       atol=5e-4, rtol=1e-4, err_msg=name)
-
-    def test_factored_mask(self, rng):
-        """Column masking (the sharded ragged-pad contract): masked columns
-        of S'/Y' and the residual come out exactly zero."""
-        m, y, f, vr, rho, mu, th = self._inputs(rng, 2, 40, 8, 4)
-        mask = jnp.asarray([1, 1, 1, 1, 1, 0, 0, 0], jnp.float32)
-        got = svt_kernel.subspace_apply_factored(
-            m, y, f, vr, rho, mu, th, mask=mask, interpret=True
-        )
-        want = ref.svt_subspace_apply_factored_ref(m, y, f, vr, rho, mu, th,
-                                                   mask=mask)
-        for g, w in zip(got, want):
-            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
-                                       atol=5e-4, rtol=1e-4)
-        assert float(jnp.abs(got[1][:, :, 5:]).max()) == 0.0
-        assert float(jnp.abs(got[2][:, :, 5:]).max()) == 0.0
-
-    def test_factored_rsq_tiling_invariant(self, rng):
-        """The psum-bound residual partial must not depend on block_vec."""
-        m, y, f, vr, rho, mu, th = self._inputs(rng, 2, 250, 6, 3)
-        r_small = svt_kernel.subspace_apply_factored(
-            m, y, f, vr, rho, mu, th, block_vec=16, interpret=True)[3]
-        r_full = svt_kernel.subspace_apply_factored(
-            m, y, f, vr, rho, mu, th, block_vec=512, interpret=True)[3]
-        np.testing.assert_allclose(r_small, r_full, rtol=1e-4, atol=1e-3)
-
-
 SVT_TOL = dict(atol=5e-4, rtol=1e-4)
 
 

@@ -131,21 +131,6 @@ def test_subspace_apply_compiles(bucket, one_chip):
     assert "tpu_custom_call" in text
 
 
-def test_subspace_apply_factored_compiles(bucket, one_chip):
-    b, vec, nc = bucket
-    r = 4
-    t = jax.ShapeDtypeStruct((b, vec, nc), jnp.float32, sharding=one_chip)
-    f = jax.ShapeDtypeStruct((b, vec, r), jnp.float32, sharding=one_chip)
-    v = jax.ShapeDtypeStruct((b, nc, r), jnp.float32, sharding=one_chip)
-    s = jax.ShapeDtypeStruct((b,), jnp.float32, sharding=one_chip)
-    text = compile_text(
-        lambda m, y, ff, vr, rho, mu, th: svt_subspace.subspace_apply_factored(
-            m, y, ff, vr, rho, mu, th, interpret=False),
-        t, t, f, v, s, s, s,
-    )
-    assert "tpu_custom_call" in text
-
-
 def _projection_shapes(one_chip, slots=None):
     cfg = cfglib.get_config(ARCH)
     d, r = cfg.d_model, cfg.lora.rank
@@ -194,31 +179,13 @@ def test_local_step_fits_one_chip(one_chip, smoke_args):
     assert total < HBM_BYTES, f"{shape}: {total / 2**30:.2f} GiB"
 
 
-def test_sharded_aggregation_compiles(topo, monkeypatch):
-    """--four-chips: FedRPCA with the fused tail, clients sharded 4 ways.
-    The kernels are called deep inside ``aggregate``, so the CPU backend's
-    interpret default is switched off for this compile."""
-    monkeypatch.setattr(backend, "interpret_default", lambda: False)
-    mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("data", "model"),
-                axis_types=(AxisType.Auto, AxisType.Auto))
-    rep = NamedSharding(mesh, PartitionSpec())
-    cfg = cfglib.get_config(ARCH)
-    lora = jax.eval_shape(lambda k: init_lora_params(k, cfg), jax.random.PRNGKey(0))
-    stacked = jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct((CLIENTS,) + x.shape, jnp.float32, sharding=rep),
-        lora,
-    )
-    agg = AggregatorConfig(method="fedrpca", rpca_iters=30, svt_mode="subspace",
-                           rpca_fused_tail=True)
-    text = compile_text(lambda t: aggregate(t, agg, engine="packed", mesh=mesh), stacked)
-    assert "tpu_custom_call" in text
-    assert "all-reduce" in text
-
-
 @pytest.mark.parametrize("tail", ["xla", "fused"])
-def test_sharded_gram_aggregation_compiles(topo, monkeypatch, tail):
-    """--four-chips' gram comparison: the vec rows sharded 4 ways, the
-    (B, C, C) Grams exchanged, the XLA or the fused tail on each chip."""
+@pytest.mark.parametrize("svt_mode", ["gram", "subspace"])
+def test_sharded_gram_aggregation_compiles(topo, monkeypatch, svt_mode, tail):
+    """--four-chips: FedRPCA with the vec rows sharded 4 ways, the (B, C, C)
+    Grams exchanged, in either SVT mode with the XLA or the fused tail on
+    each chip.  The kernels are called deep inside ``aggregate``, so the CPU
+    backend's interpret default is switched off for this compile."""
     monkeypatch.setattr(backend, "interpret_default", lambda: False)
     mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("data", "model"),
                 axis_types=(AxisType.Auto, AxisType.Auto))
@@ -229,7 +196,7 @@ def test_sharded_gram_aggregation_compiles(topo, monkeypatch, tail):
         lambda x: jax.ShapeDtypeStruct((CLIENTS,) + x.shape, jnp.float32, sharding=rep),
         lora,
     )
-    agg = AggregatorConfig(method="fedrpca", rpca_iters=30, svt_mode="gram",
+    agg = AggregatorConfig(method="fedrpca", rpca_iters=30, svt_mode=svt_mode,
                            rpca_fused_tail=tail == "fused")
     text = compile_text(lambda t: aggregate(t, agg, engine="packed", mesh=mesh), stacked)
     assert ("tpu_custom_call" in text) == (tail == "fused")
