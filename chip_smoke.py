@@ -3,11 +3,19 @@
 
 One chip (no arguments):
   1. device check — the first JAX device must be a TPU, else exit 1;
-  2. training — ``repro.launch.train.main`` in-process on stablelm-1.6b at
+  2. SSD kernels — the Pallas chunk kernels (``models/ssd.py::ssd_pallas``)
+     against ``ssd_chunked`` at granite-4.0-h-small's mixer widths under
+     ``vmap`` over 8 clients: the output, the final state and the
+     gradients in x, dt, A_log, B and C, each as its largest error relative
+     to ``ssd_chunked`` at ``"highest"``.  At ``"highest"`` the kernels must
+     be within ``SSD_TOL`` of it; at the default precision no further from
+     it than ``SSD_SLACK`` times ``ssd_chunked``'s own default-precision
+     error;
+  3. training — ``repro.launch.train.main`` in-process on stablelm-1.6b at
      full published width: 8 clients, 4 rounds of 2 local Adam steps,
      packed FedRPCA with subspace SVT, the cross-round subspace carry and
      the staleness-1 pipeline;
-  3. aggregation parity — the fused Pallas tail, the XLA tail and the
+  4. aggregation parity — the fused Pallas tail, the XLA tail and the
      per-leaf reference engine, under both SVT modes, on the last round's
      real client deltas (aggregated update and the RPCA decomposition (L, S)
      of the packed bucket); fused vs XLA (L, S) also on planted low-rank +
@@ -96,6 +104,12 @@ TOL_SHARDED_DECOMP = {
 NOISE_CAP = 1e-3
 # Planted deltas: rank-2 client structure plus 1% spikes of 2x its scale.
 PLANTED_RANK, PLANTED_DENSITY, PLANTED_SPIKE = 2, 1e-2, 2.0
+# The SSD check: granite-4.0-h-small's mixer (128 heads of 64, state 128,
+# 256-token chunks) on 8 clients' 1,024 tokens.  Both paths at "highest" sum
+# the same float32 products in other orders; at the default precision both
+# round their matmul operands to bf16, in other places.
+SSD_SHAPE = dict(clients=8, seq=1024, heads=128, head_dim=64, state=128)
+SSD_CHUNK, SSD_TOL, SSD_SLACK = 256, 1e-4, 2.0
 
 
 class Smoke:
@@ -334,6 +348,66 @@ def sharded_parity(smoke: Smoke, inputs: dict) -> None:
     runs.compare("gram/sharded-LS", "gram/one-chip-LS", TOL_SHARDED_DECOMP)
 
 
+def ssd_inputs(clients, seq, heads, head_dim, state):
+    """Granite-like mixer inputs: dt from softplus over granite's dt_bias
+    range, A_log over log(1..16), unit-scale x, B and C, a loss weight on
+    each output."""
+    ks = jax.random.split(jax.random.PRNGKey(5), 7)
+    bias = jnp.log(jnp.expm1(jnp.linspace(1e-3, 1e-1, heads)))
+    x = jax.random.normal(ks[0], (clients, 1, seq, heads, head_dim))
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (clients, 1, seq, heads)) + bias)
+    a_log = jnp.log(jnp.linspace(1.0, 16.0, heads))
+    bm = jax.random.normal(ks[2], (clients, 1, seq, state))
+    cm = jax.random.normal(ks[3], (clients, 1, seq, state))
+    d = jax.random.normal(ks[4], (heads,))
+    wy = jax.random.normal(ks[5], x.shape)
+    wh = jax.random.normal(ks[6], (clients, 1, heads, head_dim, state))
+    return (x, dt, a_log, bm, cm, d), (wy, wh)
+
+
+def ssd_parity(smoke: Smoke) -> None:
+    from repro.models import ssd
+
+    args, (wy, wh) = ssd_inputs(**SSD_SHAPE)
+    chunk = SSD_CHUNK
+
+    def outputs(core, precision):
+        def per_client(x, dt, bm, cm, a_log, d):
+            return core(x, dt, a_log, bm, cm, d, chunk)
+
+        def run(x, dt, a_log, bm, cm, d):
+            return jax.vmap(per_client, in_axes=(0, 0, 0, 0, None, None))(x, dt, bm, cm, a_log, d)
+
+        def loss(*a):
+            y, h = jax.checkpoint(run)(*a)
+            return jnp.sum(y * wy) + jnp.sum(h * wh)
+
+        with jax.default_matmul_precision(precision):
+            fwd = jax.jit(run).lower(*args).compile()
+            grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(*args).compile()
+        out = dict(zip(("y", "state", "dx", "ddt", "dA_log", "dB", "dC"),
+                       (*fwd(*args), *grad(*args))))
+        return out, "tpu_custom_call" in fwd.as_text() and "tpu_custom_call" in grad.as_text()
+
+    ref, _ = outputs(ssd.ssd_chunked, "highest")
+    runs = {}
+    for name, core, precision in (("kernels/highest", ssd.ssd_pallas, "highest"),
+                                  ("kernels/default", ssd.ssd_pallas, None),
+                                  ("xla/default", ssd.ssd_chunked, None)):
+        runs[name], kernel = outputs(core, precision)
+        smoke.check(kernel == name.startswith("kernels"),
+                    f"ssd {name}: TPU kernels compiled in: {kernel}")
+    for name, want in ref.items():
+        scale = float(jnp.max(jnp.abs(want)))
+        rel = {k: float(jnp.max(jnp.abs(r[name] - want))) / scale for k, r in runs.items()}
+        print(f"ssd {name}: scale={scale!r} rel_err={json.dumps(rel)}", flush=True)
+        smoke.check(rel["kernels/highest"] <= SSD_TOL,
+                    f"ssd {name}: kernels at highest {rel['kernels/highest']!r} > {SSD_TOL}")
+        smoke.check(rel["kernels/default"] <= SSD_SLACK * rel["xla/default"],
+                    f"ssd {name}: kernels at default precision {rel['kernels/default']!r}, "
+                    f"more than {SSD_SLACK} x ssd_chunked's {rel['xla/default']!r}")
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__,
                                  formatter_class=argparse.RawDescriptionHelpFormatter)
@@ -370,6 +444,7 @@ def main(argv=None) -> int:
     if args.four_chips:
         summary = run_training(smoke, ["--mesh-shards", "4", "--rpca-fused-tail"], 2)
     else:
+        ssd_parity(smoke)
         summary = run_training(smoke, [], 4)
     print(f"after training: peak_bytes_in_use={peak_bytes(dev)} "
           f"compile_s={smoke.compile_s:.3f}", flush=True)

@@ -8,20 +8,20 @@
                     gathered multi-adapter pool variant (scalar-prefetch
                     block gather; Punica/S-LoRA-style SGMV)
   local_attention — flash-style causal sliding-window attention
-  ssd_scan        — Mamba-2 chunked SSD with VMEM-resident recurrent state
+  ssd_scan        — Mamba-2 SSD chunk core, forward and backward, with the
+                    decay mask and scores in VMEM (``ssd_chunk_scan``)
 
 Execution mode (compiled vs interpret) is resolved per-call by
 ``repro.kernels.backend``.  Validated against ``repro.kernels.ref`` in
 interpret mode on CPU (TPU is the compile target; see tests/test_kernels.py
 shape/dtype sweeps).
 """
-from repro.kernels import backend, ops, ref, rpca_admm, svt_subspace
+from repro.kernels import backend, ops, ref, rpca_admm, ssd_scan, svt_subspace
 from repro.kernels.ops import (
     gathered_lora_matmul,
     local_attention,
     lora_matmul,
     soft_threshold,
-    ssd_scan,
 )
 from repro.kernels.rpca_admm import admm_tail
 from repro.kernels.svt_subspace import subspace_apply

@@ -14,7 +14,6 @@ from repro.kernels import backend
 from repro.kernels import local_attention as _la
 from repro.kernels import lora_matmul as _lm
 from repro.kernels import soft_threshold as _st
-from repro.kernels import ssd_scan as _ss
 
 def soft_threshold(x: jnp.ndarray, t, *, interpret: Optional[bool] = None) -> jnp.ndarray:
     """Kernel-backed shrinkage; reshapes any rank to 2-D tiles."""
@@ -105,10 +104,3 @@ def local_attention(
         return jnp.transpose(out.reshape(bsz, h, s, d), (0, 2, 1, 3))
     return _la.local_attention(q, k, v, window=window, causal=causal, interpret=interpret)
 
-
-def ssd_scan(
-    x: jnp.ndarray, da: jnp.ndarray, b: jnp.ndarray, c: jnp.ndarray, *,
-    chunk: int = 256, interpret: Optional[bool] = None,
-) -> jnp.ndarray:
-    interpret = backend.resolve_interpret(interpret)
-    return _ss.ssd_scan(x, da, b, c, chunk=chunk, interpret=interpret)

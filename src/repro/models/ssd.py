@@ -11,8 +11,10 @@ a small recurrent state (H, P, N) is propagated.  The cross-chunk recurrence
 uses ``lax.associative_scan`` (log-depth — TPU-friendly; a sequential scan
 would serialize 2048 steps at 500k context).
 
-Pure-jnp implementation; ``repro.kernels.ssd_scan`` is the Pallas TPU kernel
-for the intra-chunk contraction with this module as its oracle.
+``ssd_chunked`` is the pure-jnp form: the CPU path and the oracle of
+``ssd_pallas``, which runs the chunk core through the Pallas kernels of
+``repro.kernels.ssd_scan`` on a TPU.  ``apply_ssd`` takes the kernels
+wherever Pallas compiles for the backend and the heads block into 128 lanes.
 """
 from __future__ import annotations
 
@@ -21,6 +23,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import backend, ssd_scan
 from repro.models import layers
 from repro.models.kvcache import SSMState
 
@@ -85,7 +88,6 @@ def ssd_chunked(
     c_mat: jnp.ndarray,  # (B, S, N)
     d_skip: jnp.ndarray,  # (H,)
     chunk: int,
-    h_init: jnp.ndarray | None = None,  # (B, H, P, N)
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Returns (y (B,S,H,P), final_state (B,H,P,N))."""
     bsz, s, h, p = x.shape
@@ -126,19 +128,11 @@ def ssd_chunked(
         d2, s2 = right
         return d1 * d2, s1 * d2[..., None, None] + s2
 
-    decays, states_inclusive = jax.lax.associative_scan(
-        combine, (chunk_decay, states), axis=1
-    )
-    if h_init is not None:
-        states_inclusive = states_inclusive + decays[..., None, None] * h_init[:, None]
+    _, states_inclusive = jax.lax.associative_scan(combine, (chunk_decay, states), axis=1)
     final_state = states_inclusive[:, -1]
     # State *entering* each chunk = inclusive scan shifted right by one.
     h_prev = jnp.concatenate(
-        [
-            (h_init if h_init is not None else jnp.zeros_like(final_state))[:, None],
-            states_inclusive[:, :-1],
-        ],
-        axis=1,
+        [jnp.zeros_like(final_state)[:, None], states_inclusive[:, :-1]], axis=1
     )  # (B,nc,H,P,N)
 
     # --- inter-chunk output ---
@@ -149,6 +143,34 @@ def ssd_chunked(
     y = jnp.reshape(y, (bsz, s + pad, h, p))[:, :s]
     y = y + x[:, :s] * d_skip[None, None, :, None]
     return y, final_state
+
+
+def ssd_pallas(
+    x: jnp.ndarray,  # (B, S, H, P)
+    dt: jnp.ndarray,  # (B, S, H) positive
+    a_log: jnp.ndarray,  # (H,)
+    b_mat: jnp.ndarray,  # (B, S, N)
+    c_mat: jnp.ndarray,  # (B, S, N)
+    d_skip: jnp.ndarray,  # (H,)
+    chunk: int,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``ssd_chunked`` with the chunk core in the Pallas kernels: the dt
+    premultiplication, the log-decays, their within-chunk cumsum and the D
+    skip stay here, in XLA."""
+    bsz, s, h, p = x.shape
+    n = b_mat.shape[-1]
+    pad = (-s) % chunk
+    xdt = jnp.reshape(x * dt[..., None], (bsz, s, h * p))
+    da = dt * -jnp.exp(a_log)[None, None, :]
+    if pad:
+        xdt, da, b_mat, c_mat = (jnp.pad(t, ((0, 0), (0, pad), (0, 0)))
+                                 for t in (xdt, da, b_mat, c_mat))
+    sp = s + pad
+    cum = jnp.reshape(jnp.cumsum(jnp.reshape(da, (bsz, sp // chunk, chunk, h)), axis=2),
+                      (bsz, sp, h))
+    y, h_final = ssd_scan.ssd_chunk_scan(xdt, cum, b_mat, c_mat, chunk=chunk)
+    y = jnp.reshape(y, (bsz, sp, h, p))[:, :s] + x * d_skip[None, None, :, None]
+    return y, jnp.transpose(jnp.reshape(h_final, (bsz, n, h, p)), (0, 2, 3, 1))
 
 
 def ssd_decode_step(
@@ -204,7 +226,9 @@ def apply_ssd(
         xbc = _causal_conv(xbc, params["conv_w"].astype(x.dtype), params["conv_b"])
         xs, b_mat, c_mat = jnp.split(xbc, [dims["d_inner"], dims["d_inner"] + n_state], -1)
         xs = jnp.reshape(xs, (*xs.shape[:2], h_heads, p_dim))
-        y, h_final = ssd_chunked(
+        use_kernels = (not backend.interpret_default()
+                       and ssd_scan.heads_per_block(h_heads, p_dim) is not None)
+        y, h_final = (ssd_pallas if use_kernels else ssd_chunked)(
             xs.astype(jnp.float32),
             dt,
             params["A_log"],

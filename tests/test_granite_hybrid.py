@@ -18,7 +18,7 @@ from bench import hybrid_round_cell as hc
 from repro.core import AggregatorConfig, aggregate
 from repro.core.engine import pack, unpack
 from repro.core.stacking import infer_layer_axes
-from repro.models import forward, loss_fn, moe
+from repro.models import forward, loss_fn, moe, ssd
 
 SEQ = 64  # 4 SSD chunks of 16
 
@@ -63,6 +63,18 @@ def flat(tree, cfg):
 
 
 def test_logits_loss_and_lora_grads_match_the_reference(ref):
+    """Through the mixer's XLA chunk core, the CPU path."""
+    _match_the_reference(ref)
+
+
+def test_logits_loss_and_lora_grads_match_the_reference_through_the_ssd_kernels(
+        ref, monkeypatch):
+    """Through the mixer's Pallas chunk kernels, the TPU path (interpreted)."""
+    monkeypatch.setattr(ssd, "ssd_chunked", ssd.ssd_pallas)
+    _match_the_reference(ref)
+
+
+def _match_the_reference(ref):
     cfg = config()
     base, lora = weights(ref, cfg)
     mcfg = hc.model_config(cfg)

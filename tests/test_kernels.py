@@ -323,35 +323,136 @@ class TestLocalAttention:
 
 
 class TestSSDScan:
-    @pytest.mark.parametrize("s,chunk", [(64, 16), (96, 32), (100, 32), (256, 256)])
-    def test_sweep(self, s, chunk, rng):
-        bh, p, n = 3, 16, 8
-        x = arr(rng, (bh, s, p), jnp.float32)
-        da = -jnp.abs(arr(rng, (bh, s), jnp.float32)) * 0.1
-        b = arr(rng, (bh, s, n), jnp.float32)
-        c = arr(rng, (bh, s, n), jnp.float32)
-        got = ops.ssd_scan(x, da, b, c, chunk=chunk)
-        want = ref.ssd_scan_ref(x, da, b, c, chunk)
-        np.testing.assert_allclose(got, want, atol=5e-5, rtol=1e-3)
+    """The SSD chunk kernels (``kernels/ssd_scan.py``), through the model's
+    glue ``models/ssd.py::ssd_pallas`` or called directly, against the
+    sequential reference and ``ssd_chunked``.  Matmuls at "highest": at the
+    default precision the kernels round their operands to bf16, as XLA's TPU
+    backend does."""
 
-    def test_matches_model_ssd_chunked(self, rng):
-        """Kernel vs the model's associative-scan SSD (same math, no D skip)."""
-        from repro.models.ssd import ssd_chunked
+    @pytest.fixture(autouse=True)
+    def highest(self):
+        with jax.default_matmul_precision("highest"):
+            yield
 
-        bsz, s, h, p, n = 2, 64, 3, 8, 4
+    @staticmethod
+    def inputs(rng, bsz, s, h, p, n):
         x = arr(rng, (bsz, s, h, p), jnp.float32)
         dt = jnp.abs(arr(rng, (bsz, s, h), jnp.float32)) * 0.1 + 0.01
         a_log = jnp.asarray(np.log(np.linspace(1.0, 4.0, h)), jnp.float32)
-        bm = arr(rng, (bsz, s, n), jnp.float32)
-        cm = arr(rng, (bsz, s, n), jnp.float32)
-        y_model, _ = ssd_chunked(x, dt, a_log, bm, cm, jnp.zeros((h,)), chunk=16)
+        return x, dt, a_log, arr(rng, (bsz, s, n), jnp.float32), arr(rng, (bsz, s, n), jnp.float32)
 
-        # kernel form: fold (B, H) and premultiply by dt
-        a = -jnp.exp(a_log)
-        da = (dt * a[None, None, :]).transpose(0, 2, 1).reshape(bsz * h, s)
+    @staticmethod
+    def close(got, want, rel=2e-6):
+        """Within ``rel`` of the largest entry of ``want``."""
+        got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+        np.testing.assert_allclose(got, want, rtol=0, atol=rel * np.max(np.abs(want)))
+
+    @pytest.mark.parametrize("s,chunk", [(64, 16), (96, 32), (100, 32), (256, 256)])
+    def test_sweep(self, s, chunk, rng):
+        """Kernels vs the exact sequential scan, S a multiple of the chunk or not."""
+        from repro.models.ssd import ssd_pallas
+
+        bsz, h, p, n = 1, 2, 64, 8
+        x, dt, a_log, bm, cm = self.inputs(rng, bsz, s, h, p, n)
+        y, _ = ssd_pallas(x, dt, a_log, bm, cm, jnp.zeros((h,)), chunk)
+        da = (dt * -jnp.exp(a_log)).transpose(0, 2, 1).reshape(bsz * h, s)
         xk = (x * dt[..., None]).transpose(0, 2, 1, 3).reshape(bsz * h, s, p)
-        bk = jnp.repeat(bm, h, axis=0).reshape(bsz, h, s, n).reshape(bsz * h, s, n)
-        ck = jnp.repeat(cm, h, axis=0).reshape(bsz, h, s, n).reshape(bsz * h, s, n)
-        y_kern = ops.ssd_scan(xk, da, bk, ck, chunk=16)
-        y_kern = y_kern.reshape(bsz, h, s, p).transpose(0, 2, 1, 3)
-        np.testing.assert_allclose(y_model, y_kern, atol=5e-5, rtol=1e-3)
+        per_head = lambda m: jnp.broadcast_to(m[:, None], (bsz, h, s, n)).reshape(bsz * h, s, n)
+        want = ref.ssd_scan_ref(xk, da, per_head(bm), per_head(cm), chunk)
+        want = want.reshape(bsz, h, s, p).transpose(0, 2, 1, 3)
+        np.testing.assert_allclose(y, want, atol=5e-5, rtol=1e-3)
+
+    def test_matches_model_ssd_chunked(self, rng):
+        """``ssd_chunk_scan`` called directly: the model's chunk core from the
+        dt-premultiplied x and the within-chunk cumulative log-decays."""
+        from repro.kernels.ssd_scan import ssd_chunk_scan
+        from repro.models.ssd import ssd_chunked
+
+        bsz, s, h, p, n, chunk = 2, 64, 4, 32, 4, 16
+        x, dt, a_log, bm, cm = self.inputs(rng, bsz, s, h, p, n)
+        y_model, h_model = ssd_chunked(x, dt, a_log, bm, cm, jnp.zeros((h,)), chunk)
+        da = (dt * -jnp.exp(a_log)).reshape(bsz, s // chunk, chunk, h)
+        cum = jnp.cumsum(da, axis=2).reshape(bsz, s, h)
+        xk = (x * dt[..., None]).reshape(bsz, s, h * p)
+        y, h_final = ssd_chunk_scan(xk, cum, bm, cm, chunk=chunk)
+        self.close(y.reshape(bsz, s, h, p), y_model)
+        self.close(h_final.reshape(bsz, n, h, p).transpose(0, 2, 3, 1), h_model)
+
+    @pytest.mark.parametrize("bsz,s,h,p,n,chunk", [
+        (1, 100, 4, 64, 16, 32),  # S not a multiple of Q; 4 chunks; 2 head blocks of 2
+        (2, 64, 8, 32, 8, 16),  # 2 head blocks of 4
+        (1, 48, 3, 128, 8, 16),  # 3 head blocks of 1
+    ])
+    def test_forward_matches_ssd_chunked(self, bsz, s, h, p, n, chunk, rng):
+        from repro.models.ssd import ssd_chunked, ssd_pallas
+
+        args = self.inputs(rng, bsz, s, h, p, n) + (arr(rng, (h,), jnp.float32),)
+        y, state = ssd_pallas(*args, chunk)
+        y_want, state_want = ssd_chunked(*args, chunk)
+        self.close(y, y_want)
+        self.close(state, state_want)
+
+    @pytest.mark.parametrize("bsz,s,h,p,n,chunk", [
+        (1, 100, 4, 64, 16, 32),
+        (2, 48, 8, 32, 8, 16),
+    ])
+    def test_grads_match_ssd_chunked(self, bsz, s, h, p, n, chunk, rng):
+        """The custom_vjp's gradients in x, dt, A_log, B and C, through both
+        outputs, against autodiff of ``ssd_chunked``."""
+        from repro.models.ssd import ssd_chunked, ssd_pallas
+
+        args = self.inputs(rng, bsz, s, h, p, n) + (arr(rng, (h,), jnp.float32),)
+        wy = arr(rng, (bsz, s, h, p), jnp.float32)
+        wh = arr(rng, (bsz, h, p, n), jnp.float32)
+
+        def grads(core):
+            def loss(*a):
+                y, state = core(*a, chunk)
+                return jnp.sum(y * wy) + jnp.sum(state * wh)
+            return jax.grad(loss, argnums=(0, 1, 2, 3, 4))(*args)
+
+        for got, want in zip(grads(ssd_pallas), grads(ssd_chunked)):
+            self.close(got, want, rel=1e-5)
+
+    def test_vmap_over_clients_under_checkpoint(self, rng):
+        """As the local step runs the mixer: vmapped over clients, rematted."""
+        from repro.models.ssd import ssd_chunked, ssd_pallas
+
+        clients, s, h, p, n, chunk = 3, 64, 4, 32, 8, 16
+        x, dt, a_log, bm, cm = self.inputs(rng, clients, s, h, p, n)
+        d = arr(rng, (h,), jnp.float32)
+        w = arr(rng, (clients, s, h, p), jnp.float32)
+
+        def grads(core):
+            def client(x, dt, bm, cm, a_log):
+                y, _ = core(x[None], dt[None], a_log, bm[None], cm[None], d, chunk)
+                return y[0]
+
+            def loss(x, dt, bm, cm, a_log):
+                y = jax.checkpoint(jax.vmap(client, in_axes=(0, 0, 0, 0, None)))(
+                    x, dt, bm, cm, a_log)
+                return jnp.sum(y * w)
+            return jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))(x, dt, bm, cm, a_log)
+
+        for got, want in zip(grads(ssd_pallas), grads(ssd_chunked)):
+            self.close(got, want, rel=1e-5)
+
+    def test_default_precision_is_one_bf16_pass(self, rng):
+        """Outside "highest" the kernels' matmul operands are bf16, as a float32
+        dot's at XLA's default precision on a TPU: within bf16 rounding of the
+        float32 result, and not equal to it."""
+        from repro.models.ssd import ssd_chunked, ssd_pallas
+
+        args = self.inputs(rng, 1, 64, 2, 64, 16) + (jnp.zeros((2,)),)
+        want, _ = ssd_chunked(*args, 16)
+        with jax.default_matmul_precision("default"):
+            got, _ = ssd_pallas(*args, 16)
+        self.close(got, want, rel=2e-2)
+        assert float(jnp.max(jnp.abs(got - want))) > 0.0
+
+    @pytest.mark.parametrize("h,p,hb", [(128, 64, 2), (24, 64, 2), (16, 32, 4), (3, 128, 1),
+                                        (2, 256, 1), (3, 64, None), (4, 96, None)])
+    def test_heads_per_block(self, h, p, hb):
+        from repro.kernels.ssd_scan import heads_per_block
+
+        assert heads_per_block(h, p) == hb

@@ -6,7 +6,10 @@ Here each kernel is compiled at stablelm-1.6b's widths for a v5e that is
 described, not attached, so a refusal fails the suite with no chip.  So
 are the two programs ``chip_smoke.py`` runs that could not be tried
 otherwise: the local step at its sequence length (it must fit one chip's
-16 GiB) and the aggregation sharded over a 2x2 mesh.
+16 GiB) and the aggregation sharded over a 2x2 mesh.  The SSD chunk kernels
+compile at granite-4.0-h-small's mixer widths, and the granite cell's local
+step, lowered for the chip, runs them in place of the (Q, Q) float32
+tensors of ``ssd_chunked``.
 
 The topology is described inside a fixture, never at import: only one
 process may hold the TPU library, and every pytest worker imports this
@@ -15,6 +18,7 @@ chip's entries could not be read back).
 """
 import importlib.util
 import os
+import re
 from pathlib import Path
 
 import jax
@@ -26,7 +30,7 @@ from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec, SingleDev
 from repro import configs as cfglib
 from repro.core import AggregatorConfig, aggregate
 from repro.core import engine as engine_lib
-from repro.kernels import backend, rpca_admm, svt_subspace
+from repro.kernels import backend, rpca_admm, ssd_scan, svt_subspace
 from repro.kernels.lora_matmul import gathered_lora_matmul, lora_matmul
 from repro.launch import steps as steps_lib
 from repro.models import init_lora_params, init_params
@@ -36,6 +40,8 @@ CLIENTS = 8
 TOKENS = 512  # rows of one local-step activation tile batch
 ADAPTER_SLOTS = 8
 HBM_BYTES = 16 * 2**30  # one v5e
+# granite-4.0-h-small's mixer (``bench/configs``) on the cell's 8 clients x 1,024 tokens.
+SSD = dict(clients=8, seq=1024, heads=128, head_dim=64, state=128, chunk=256)
 
 
 @pytest.fixture(scope="module")
@@ -201,3 +207,40 @@ def test_sharded_gram_aggregation_compiles(topo, monkeypatch, svt_mode, tail):
     text = compile_text(lambda t: aggregate(t, agg, engine="packed", mesh=mesh), stacked)
     assert ("tpu_custom_call" in text) == (tail == "fused")
     assert "all-gather" in text
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_ssd_scan_compiles(one_chip, direction):
+    c, s, h, p, n = (SSD[k] for k in ("clients", "seq", "heads", "head_dim", "state"))
+    f = lambda *shape: jax.ShapeDtypeStruct((c, 1) + shape, jnp.float32, sharding=one_chip)
+    fwd = jax.vmap(lambda *a: ssd_scan.ssd_chunk_scan(*a, chunk=SSD["chunk"], interpret=False))
+    bwd = jax.grad(lambda *a: sum(jnp.sum(t * t) for t in fwd(*a)), argnums=(0, 1, 2, 3))
+    text = compile_text(fwd if direction == "forward" else bwd,
+                        f(s, h * p), f(s, h), f(s, n), f(s, n))
+    assert text.count("tpu_custom_call") == (1 if direction == "forward" else 2)
+
+
+def test_granite_local_step_runs_the_ssd_kernels(one_chip, monkeypatch):
+    """The granite cell's local step (remat per block, 2 Adam steps, 8
+    clients x 1,024 tokens) lowered for the chip: each of its 9 mamba mixers
+    runs the forward kernel twice (forward and recomputation) and the
+    backward once, and no (..., 128 heads, 256, 256) float32 tensor is
+    left.  ``apply_ssd`` asks the backend, which here is the CPU's."""
+    from bench import harness
+    from bench import hybrid_round_cell as hc
+
+    monkeypatch.setattr(backend, "interpret_default", lambda: False)
+    mcfg = hc.model_config(harness.load_json(f"{harness.BENCH}/configs/granite-4.0-h-small.json"))
+    on_chip = lambda t: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), t)
+    key = jax.random.PRNGKey(0)
+    base = on_chip(jax.eval_shape(lambda k: init_params(k, mcfg), key))
+    lora = on_chip(jax.eval_shape(lambda k: init_lora_params(k, mcfg), key))
+    tok = jax.ShapeDtypeStruct((SSD["clients"], 1, SSD["seq"]), jnp.int32, sharding=one_chip)
+    step = steps_lib.make_local_step(mcfg, local_lr=1e-3, local_steps=2,
+                                     local_optimizer="adam", remat=True)
+    text = jax.jit(step).lower(base, lora, {"tokens": tok, "labels": tok}, on_chip(key)).as_text()
+    mixers = mcfg.layer_pattern.count("ssd")
+    assert mixers == 9
+    assert text.count("tpu_custom_call") == 3 * mixers
+    assert not re.search(r"tensor<[0-9x]*x128x256x256xf32>", text)
